@@ -47,6 +47,7 @@ from repro.io.tiers import (
     TierSpec,
     TPU_V5E_SYSTEM,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import (
     EngineConfig,
     InferenceRequest,
@@ -241,6 +242,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default="BENCH_autotune.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report = run()
     validate_report(report)

@@ -34,6 +34,7 @@ from benchmarks.common import SCALE
 from repro.core import ShardPlacementPass, plan_memory_dense_features
 from repro.data import generate_sbm_graph, normalized_adjacency
 from repro.io.tiers import ICI_RING
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import EngineConfig, InferenceRequest, ServingEngine
 
 N_VERTICES = max(2_048, int(4_000_000 * SCALE))
@@ -208,6 +209,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--out", default="BENCH_partition.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report = run(args.seed)
     validate_report(report)

@@ -30,6 +30,7 @@ from repro.core import EDFOrderingPass, plan_memory_dense_features
 from repro.data import (
     SUITESPARSE_SPECS, generate_graph, normalized_adjacency, scaled_spec,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import (
     ContinuousServer, EngineConfig, InferenceRequest, ServingEngine,
     VirtualClock, bursty_trace, poisson_trace, replay_continuous,
@@ -202,6 +203,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=3)
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     kinds = [k.strip() for k in args.traces.split(",") if k.strip()]
     report = run(kinds, args.requests, args.seed)

@@ -31,6 +31,7 @@ import numpy as np
 
 from benchmarks.common import SCALE, dataset
 from repro.core import plan_memory_dense_features
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import EngineConfig, InferenceRequest, ServingEngine
 from repro.sparse import apply_edge_updates
 
@@ -217,6 +218,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=5)
     ap.add_argument("--out", default="BENCH_update.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     sizes = sorted({int(k) for k in args.deltas.split(",") if k.strip()})
     report = run(sizes, args.seed)

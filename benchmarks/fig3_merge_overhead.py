@@ -14,6 +14,8 @@ from benchmarks.common import (
     budget_for, csv_row, dataset, feature_spec, run_sched, SCALE,
 )
 
+from repro.launch.compile_cache import enable_compile_cache
+
 DATASETS = ["kP1a", "kU1a", "kV2a"]
 
 
@@ -56,4 +58,5 @@ def run() -> List[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -22,6 +22,7 @@ from benchmarks.common import (
 )
 from repro.core import gcn_epoch
 from repro.io.tiers import PAPER_GPU_SYSTEM
+from repro.launch.compile_cache import enable_compile_cache
 
 DATASETS = ["rUSA", "kV2a", "kU1a", "socLJ1", "kP1a"]
 SCHEDS = ["maxmemory", "ucg", "etc", "aires"]
@@ -93,5 +94,7 @@ def run_execute(scale_down: float = 0.05) -> List[str]:
 
 if __name__ == "__main__":
     import sys
+
+    enable_compile_cache()
     out = run_execute() if "--execute" in sys.argv else run()
     print("\n".join(out))

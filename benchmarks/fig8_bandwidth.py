@@ -13,6 +13,8 @@ from benchmarks.common import (
     SCALE, budget_for, csv_row, dataset, feature_spec, run_sched,
 )
 
+from repro.launch.compile_cache import enable_compile_cache
+
 DATASETS = ["rUSA", "kV2a", "kU1a", "socLJ1", "kP1a", "kA2a", "kV1r"]
 
 
@@ -40,4 +42,5 @@ def run() -> List[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -35,6 +35,7 @@ from repro.core import (
 )
 from repro.io import ShardedSegmentCache, TieredSegmentCache
 from repro.io.tiers import PAPER_GPU_SYSTEM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sparse.partition import partition_graph
 
 DATASET = "kV2a"
@@ -140,6 +141,7 @@ def main(argv=None) -> None:
                     help="add a partition-aware owner-map arm next to the "
                          "shard arm (requires --shards)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     print("\n".join(run(cache=args.cache, shards=args.shards,
                         passes=args.passes, partition=args.partition)))
 

@@ -11,6 +11,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.kernels import bcsr_spmm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sparse import csr_from_dense, tile_csr_to_block_ell
 
 
@@ -43,4 +44,5 @@ def run() -> List[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

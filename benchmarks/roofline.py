@@ -20,6 +20,7 @@ from typing import Dict, List, Optional
 
 from repro.configs import SHAPES, get_config
 from repro.io.tiers import Path, TPU_V5E_SYSTEM
+from repro.launch.compile_cache import enable_compile_cache
 
 # Per-chip peaks sourced from the one TierSpec the whole repo prices
 # against (repro.io.tiers.TPU_V5E_SYSTEM) — the same constants the
@@ -126,4 +127,5 @@ def run() -> List[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -12,6 +12,7 @@ Prints ``name,us_per_call,derived`` CSV rows for:
 """
 from __future__ import annotations
 
+import sys
 import traceback
 
 from benchmarks import (
@@ -24,6 +25,7 @@ from benchmarks import (
     roofline,
     kernel_bench,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     fig3_merge_overhead,
@@ -37,8 +39,12 @@ MODULES = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    """Run every figure; a phase that raises is reported and the rest still
+    run, but the exit code is 1 whenever any phase failed."""
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for mod in MODULES:
         try:
             for row in mod.run():
@@ -46,7 +52,11 @@ def main() -> None:
         except Exception as err:  # noqa: BLE001
             print(f"{mod.__name__},0.0,ERROR:{type(err).__name__}:{err}")
             traceback.print_exc()
+            failed.append(mod.__name__)
+    if failed:
+        print(f"failed phases: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -12,6 +12,8 @@ from benchmarks.common import (
     SCALE, budget_for, csv_row, dataset, feature_spec, run_sched,
 )
 
+from repro.launch.compile_cache import enable_compile_cache
+
 # (dataset, budgets GB) straight from Table III.
 CASES = [
     ("kV1r", [24, 21, 19]),
@@ -39,4 +41,5 @@ def run() -> List[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -163,6 +163,8 @@ def naive_partition(a: CSR, m_a_bytes: int, value_bytes: Optional[int] = None,
         value_bytes = int(a.data.dtype.itemsize)
     per_nnz = index_bytes + value_bytes
     budget_nnz = max(1, (m_a_bytes - 2 * index_bytes) // per_nnz)
+    if a.nnz == 0:
+        return [(0, 0, False, False)]   # one empty segment covers A
     cuts = []
     pos = 0
     row_of = np.searchsorted(a.indptr, np.arange(a.nnz + 1), side="right") - 1

@@ -126,43 +126,36 @@ def generate_sbm_graph(n_vertices: int, n_edges: int, n_blocks: int = 4,
 def _dedup_csr(a: CSR, dtype) -> CSR:
     """Drop parallel edges, unit weights (shared by the generators)."""
     n = a.n_rows
-    dedup_indices = []
-    dedup_data = []
-    indptr = [0]
-    for i in range(n):
-        lo, hi = a.indptr[i], a.indptr[i + 1]
-        cols_i = np.unique(a.indices[lo:hi])
-        dedup_indices.append(cols_i)
-        dedup_data.append(np.ones(cols_i.shape[0], dtype=dtype))
-        indptr.append(indptr[-1] + cols_i.shape[0])
-    return CSR(
-        indptr=np.asarray(indptr, dtype=np.int64),
-        indices=(np.concatenate(dedup_indices) if dedup_indices
-                 else np.empty(0, np.int64)),
-        data=(np.concatenate(dedup_data) if dedup_data
-              else np.empty(0, dtype)),
-        shape=a.shape,
-    )
+    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(a.indptr))
+    order = np.lexsort((a.indices, rows))
+    rows, cols = rows[order], a.indices[order]
+    first = np.ones(cols.shape[0], dtype=bool)
+    first[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1])
+    rows, cols = rows[first], cols[first]
+    indptr = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(np.bincount(rows, minlength=n), out=indptr[1:])
+    return CSR(indptr=indptr, indices=cols,
+               data=np.ones(cols.shape[0], dtype=dtype), shape=a.shape)
 
 
 def normalized_adjacency(a: CSR) -> CSR:
     """Ã = D̂^{-1/2} (A + I) D̂^{-1/2} — paper Eq. (2), kept in CSR."""
     n = a.n_rows
-    # A + I
-    rows = []
-    for i in range(n):
-        lo, hi = a.indptr[i], a.indptr[i + 1]
-        cols = a.indices[lo:hi]
-        if i not in cols:
-            cols = np.sort(np.append(cols, i))
-        rows.append(cols)
+    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(a.indptr))
+    # A + I: a row without its diagonal gains it and is re-sorted; a row
+    # that has it keeps its column order.
+    has_diag = np.zeros(n, dtype=bool)
+    has_diag[rows[a.indices == rows]] = True
+    missing = np.nonzero(~has_diag)[0]
+    all_rows = np.concatenate([rows, missing])
+    cols = np.concatenate([a.indices, missing])
+    pos = np.concatenate([np.arange(a.nnz) - a.indptr[rows],
+                          np.zeros(missing.shape[0], dtype=np.int64)])
+    order = np.lexsort((np.where(has_diag[all_rows], pos, cols), all_rows))
+    all_rows, indices = all_rows[order], cols[order]
     indptr = np.zeros(n + 1, dtype=np.int64)
-    indptr[1:] = np.cumsum([r.shape[0] for r in rows])
-    indices = np.concatenate(rows)
+    np.cumsum(np.bincount(all_rows, minlength=n), out=indptr[1:])
     deg = np.diff(indptr).astype(np.float64)
     dinv = 1.0 / np.sqrt(np.maximum(deg, 1.0))
-    data = np.empty(indices.shape[0], dtype=a.data.dtype)
-    for i in range(n):
-        lo, hi = indptr[i], indptr[i + 1]
-        data[lo:hi] = (dinv[i] * dinv[indices[lo:hi]]).astype(a.data.dtype)
+    data = (dinv[all_rows] * dinv[indices]).astype(a.data.dtype)
     return CSR(indptr=indptr, indices=indices, data=data, shape=a.shape)

@@ -16,8 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -83,7 +82,7 @@ def decode_attention_pallas(
     kernel = functools.partial(_decode_kernel, bs=block_s, scale=scale)
     out = pl.pallas_call(
         kernel,
-        grid_spec=compat.prefetch_scalar_grid_spec(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b_sz, n_kv, n_s),
             in_specs=[
@@ -97,13 +96,13 @@ def decode_attention_pallas(
             out_specs=pl.BlockSpec((1, 1, group, d),
                                    lambda b, h, s, lens_ref: (b, h, 0, 0)),
             scratch_shapes=[
-                compat.VMEM((group, 1), jnp.float32),
-                compat.VMEM((group, 1), jnp.float32),
-                compat.VMEM((group, d), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, d), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b_sz, n_kv, group, d), q.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,8 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -114,11 +113,11 @@ def flash_attention_pallas(
                                lambda b_, h_, qi, kj: (b_, h_, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[
-            compat.VMEM((block_q, 1), jnp.float32),
-            compat.VMEM((block_q, 1), jnp.float32),
-            compat.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -35,7 +35,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import SHAPES, arch_ids, get_config, shape_applicable
 from repro.launch.hlo_analysis import collective_bytes, collective_count
-from repro.kernels.compat import use_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.sharding import (
     batch_pspec, opt_state_pspecs, state_pspecs, tree_pspecs,
@@ -176,7 +175,7 @@ def _body_cost(cfg, mesh, mesh_axes, shape, kind: str, abs_params,
                  *[_tree_sh(mesh, sp) for sp in unit_specs],
                  *[_tree_sh(mesh, sp) for sp in state_specs])
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(body, in_shardings=in_sh).lower(*args)
         compiled = lowered.compile()
     return _analyze(lowered, compiled)
@@ -321,7 +320,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         args = tuple(args)
 
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             t_l = time.time()
             lowered = step.lower(*args)
             result["lower_s"] = round(time.time() - t_l, 2)

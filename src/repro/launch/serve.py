@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import (
     init_params, encode, init_decode_state, decode_step,
 )
@@ -251,6 +252,7 @@ def main(argv=None) -> None:
                     help="continuous mode: number of arrivals in the trace")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.mode == "continuous":
         _, summary = serve_continuous(trace=args.trace,

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.checkpoint import Checkpointer, latest_step
 from repro.configs import get_config
 from repro.data import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.runtime import Supervisor, SupervisorConfig
 from repro.train import TrainLoopConfig, make_optimizer, train_loop
@@ -33,6 +34,7 @@ def main(argv=None) -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(0)

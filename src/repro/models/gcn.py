@@ -40,14 +40,23 @@ def gcn_init(cfg: GCNConfig, key: jax.Array) -> Dict[str, jnp.ndarray]:
     return params
 
 
+def matmul_precision(dtype) -> Optional[jax.lax.Precision]:
+    """Float32 matmuls run at full float32 (a TPU's default is one bfloat16
+    pass on the MXU); narrower dtypes keep the default (None)."""
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
+            else None)
+
+
 def _aggregate(a_dense: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
-    return jnp.dot(a_dense, h, preferred_element_type=jnp.float32).astype(h.dtype)
+    return jnp.dot(a_dense, h, preferred_element_type=jnp.float32,
+                   precision=matmul_precision(h.dtype)).astype(h.dtype)
 
 
 def gcn_forward(cfg: GCNConfig, params, a, h0: jnp.ndarray,
                 engine: Optional[object] = None) -> jnp.ndarray:
     """a: dense jnp array (in-core) or CSR (out-of-core with engine)."""
     n_layers = len([k for k in params if k.startswith("w")])
+    precision = matmul_precision(cfg.dtype)
     h = h0
     for i in range(n_layers):
         if cfg.out_of_core and isinstance(a, CSR):
@@ -55,7 +64,7 @@ def gcn_forward(cfg: GCNConfig, params, a, h0: jnp.ndarray,
             x = engine(a, h)                      # streamed Ã·H
         else:
             x = _aggregate(a, h)
-        h = x @ params[f"w{i}"] + params[f"b{i}"]
+        h = jnp.dot(x, params[f"w{i}"], precision=precision) + params[f"b{i}"]
         if i < n_layers - 1:
             h = jax.nn.relu(h)
     return h

@@ -28,7 +28,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.compat import axis_size
 from repro.models.config import ArchConfig
 
 
@@ -50,7 +49,7 @@ def _local_moe(cfg: ArchConfig, p, xf, model_axis: str):
     """
     t, d = xf.shape
     e, k = cfg.n_experts, cfg.top_k
-    n_ranks = axis_size(model_axis)
+    n_ranks = jax.lax.axis_size(model_axis)
     e_loc = e // n_ranks
 
     probs = jax.nn.softmax(

@@ -32,6 +32,7 @@ from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.checkpointer import (
@@ -1014,7 +1015,10 @@ class ServingEngine:
             for i, x in zip(live, aggregated):
                 ws = wss[i]
                 if layer < len(ws):
-                    h = x @ ws[layer]
+                    # Requests are float32: combine at full float32, not
+                    # the TPU's default single bfloat16 pass.
+                    h = jnp.dot(x, ws[layer],
+                                precision=jax.lax.Precision.HIGHEST)
                     if layer < len(ws) - 1:
                         h = jnp.maximum(h, 0.0)   # relu between layers
                 else:                             # bare aggregation request

@@ -7,7 +7,7 @@ directly, and records the tile topology (col_tile ids) for scalar prefetch.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Optional
 
 import numpy as np
 
@@ -37,47 +37,45 @@ def tile_csr_to_block_ell(
     n_row_blocks = max(1, (n_rows + bm - 1) // bm)
     n_col_tiles = (n_cols + bk - 1) // bk
 
-    # Pass 1: per-row-block tile occupancy (host-side, vectorized numpy).
-    per_block_tiles: List[np.ndarray] = []
-    per_block_counts: List[np.ndarray] = []
-    for rb in range(n_row_blocks):
-        lo = a.indptr[min(rb * bm, n_rows)]
-        hi = a.indptr[min((rb + 1) * bm, n_rows)]
-        tiles = a.indices[lo:hi] // bk
-        uniq, counts = np.unique(tiles, return_counts=True)
-        per_block_tiles.append(uniq)
-        per_block_counts.append(counts)
+    # Every nonzero's row block and column tile, then the distinct
+    # (row block, tile) pairs in row-block-then-tile order: the sorted
+    # populated tiles of each row block, for all row blocks at once.
+    rows = np.repeat(np.arange(n_rows, dtype=np.int64), np.diff(a.indptr))
+    cols = np.asarray(a.indices, dtype=np.int64)
+    rb = rows // bm
+    tile = cols // bk
+    pairs, pair_of_nz, counts = np.unique(
+        rb * max(n_col_tiles, 1) + tile, return_inverse=True,
+        return_counts=True)
+    pair_rb = pairs // max(n_col_tiles, 1)
+    pair_tile = pairs % max(n_col_tiles, 1)
+    width = np.bincount(pair_rb, minlength=n_row_blocks)
 
-    true_width = max((t.shape[0] for t in per_block_tiles), default=0)
+    true_width = int(width.max(initial=0))
     if ell_width is None:
         ell_width = max(1, true_width)
     ell_width = max(1, min(ell_width, n_col_tiles))
 
+    # Slot of each pair in its row block's ELL row; -1 = dropped.
+    first = np.cumsum(width) - width
+    slot = np.arange(pairs.shape[0], dtype=np.int64) - first[pair_rb]
+    for r in np.nonzero(width > ell_width)[0]:
+        # Keep the most-populated tiles (drop the tail), in tile order.
+        # AIRES schedules never get here (bucket capacity ≥ true width).
+        lo, hi = first[r], first[r] + width[r]
+        kept = np.zeros(hi - lo, dtype=bool)
+        kept[np.argsort(-counts[lo:hi], kind="stable")[:ell_width]] = True
+        slot[lo:hi] = np.where(kept, np.cumsum(kept) - 1, -1)
+
     blocks = np.zeros((n_row_blocks, ell_width, bm, bk), dtype=dtype)
     col_tile = np.full((n_row_blocks, ell_width), -1, dtype=np.int32)
-    n_tiles = np.zeros((n_row_blocks,), dtype=np.int32)
-
-    for rb in range(n_row_blocks):
-        uniq, counts = per_block_tiles[rb], per_block_counts[rb]
-        if uniq.shape[0] > ell_width:
-            # Keep the most-populated tiles (drop the tail). AIRES schedules
-            # never hit this branch (bucket capacity ≥ true width).
-            keep = np.argsort(-counts, kind="stable")[:ell_width]
-            uniq = np.sort(uniq[keep])
-        col_tile[rb, : uniq.shape[0]] = uniq
-        n_tiles[rb] = uniq.shape[0]
-
-        r0, r1 = rb * bm, min((rb + 1) * bm, n_rows)
-        for i in range(r0, r1):
-            lo, hi = a.indptr[i], a.indptr[i + 1]
-            cols = a.indices[lo:hi]
-            vals = a.data[lo:hi]
-            t = cols // bk
-            # vectorized scatter per kept tile
-            for s, tile_id in enumerate(uniq):
-                m = t == tile_id
-                if m.any():
-                    blocks[rb, s, i - r0, cols[m] - tile_id * bk] = vals[m]
+    n_tiles = np.minimum(width, ell_width).astype(np.int32)
+    kept = slot >= 0
+    col_tile[pair_rb[kept], slot[kept]] = pair_tile[kept]
+    nz_slot = slot[pair_of_nz]
+    nz = nz_slot >= 0
+    blocks[rb[nz], nz_slot[nz], (rows - rb * bm)[nz],
+           (cols - tile * bk)[nz]] = a.data[nz]
 
     return BlockELL(blocks=blocks, col_tile=col_tile, n_tiles=n_tiles,
                     bm=bm, bk=bk, n_rows=n_rows, n_cols=n_cols)

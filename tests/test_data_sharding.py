@@ -1,10 +1,9 @@
 """Graph generators, token pipeline, and sharding-rule unit tests."""
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import arch_ids, get_config
-from repro.kernels.compat import make_abstract_mesh
 from repro.data import (
     SUITESPARSE_SPECS, generate_graph, normalized_adjacency, scaled_spec,
 )
@@ -53,8 +52,8 @@ def test_token_pipeline_sharding_partition():
 
 
 MESHES = [
-    make_abstract_mesh((16, 16), ("data", "model")),
-    make_abstract_mesh((2, 16, 16), ("pod", "data", "model")),
+    AbstractMesh((16, 16), ("data", "model")),
+    AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 ]
 
 

@@ -50,6 +50,37 @@ def test_bcsr_spmm_empty_rows():
     np.testing.assert_allclose(out, dense @ h, atol=1e-5)
 
 
+@pytest.mark.parametrize("rows", [1, 3, 4, 7])
+def test_bcsr_spmm_split_calls_bitexact(rows):
+    """A segment covered by several calls (the SMEM bound on the tile
+    table) gives the single call's result bit for bit, including the last
+    call that is shifted back to overlap its predecessor."""
+    from repro.kernels.bcsr_spmm import _split_spmm, bcsr_spmm_pallas
+
+    dense = _rand_sparse(56, 48, 0.15, np.float32, seed=11)
+    ell = tile_csr_to_block_ell(csr_from_dense(dense), bm=8, bk=8)
+    h = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (48, 128)).astype(np.float32))
+    args = (jnp.asarray(ell.blocks), jnp.asarray(ell.col_tile),
+            jnp.asarray(ell.n_tiles), h)
+    kw = dict(bm=8, bk=8, bn=128, interpret=True)
+    whole = np.asarray(bcsr_spmm_pallas(*args, **kw))
+    split = np.asarray(_split_spmm(*args, out_dtype=jnp.float32, rows=rows,
+                                   **kw))
+    np.testing.assert_array_equal(split, whole)
+    np.testing.assert_allclose(whole, dense @ np.asarray(h), atol=1e-4)
+
+
+def test_smem_rows_per_call_bounds_the_tile_table():
+    from repro.kernels.bcsr_spmm import SMEM_PREFETCH_WORDS, smem_rows_per_call
+
+    assert smem_rows_per_call(64, 8) == 64
+    for n_rb, ell_w in [(4096, 16), (13789, 64), (100, 4096)]:
+        rows = smem_rows_per_call(n_rb, ell_w)
+        assert 1 <= rows < n_rb
+        assert rows * (ell_w + 1) <= SMEM_PREFETCH_WORDS
+
+
 @pytest.mark.parametrize("n,f,fo", [(24, 16, 8), (40, 24, 16)])
 def test_fused_gcn_layer(n, f, fo):
     dense = _rand_sparse(n, n, 0.2, np.float32, seed=n)

@@ -2,14 +2,9 @@
 
 Needs >1 device, so it runs in a subprocess with
 --xla_force_host_platform_device_count=8 (the main test process locked
-jax to 1 CPU device at import).
-
-Triage note (2026-07): this test's "numeric assertion failure" was API
-drift, not a routing bug — the subprocess crashed with AttributeError
-(`jax.sharding.set_mesh` / `jax.lax.axis_size` are absent on JAX 0.4.x)
-before computing anything, and the returncode assertion surfaced it as a
-failure. With the compat shims the shard_map path matches the GSPMD
-reference to <1e-4 unchanged.
+jax to 1 CPU device at import). The child is pinned to the CPU
+(`JAX_PLATFORMS=cpu`): on a machine with an accelerator it must not
+reach for a chip the parent may already hold.
 """
 import os
 import subprocess
@@ -31,15 +26,13 @@ def test_shard_map_moe_matches_reference():
         from repro.models.layers import moe_ffn
         from repro.models.transformer import _init_moe
 
-        from repro.kernels.compat import use_mesh
-
         cfg = get_config("kimi_k2_1t_a32b", smoke=True)
         cfg = dataclasses.replace(cfg, n_experts=8, top_k=2,
                                   capacity_factor=8.0)
         mesh = jax.make_mesh((2, 4), ("data", "model"))
         p = _init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out_sm, _ = jax.jit(lambda p_, x_: moe_ffn_shard_map(
                 cfg, p_, x_, mesh, ("data",), "model"))(p, x)
         out_ref, _ = moe_ffn(cfg, p, x)
@@ -47,7 +40,8 @@ def test_shard_map_moe_matches_reference():
         assert err < 1e-4, err
         print("OK", err)
     """) % (os.path.join(os.path.dirname(__file__), "..", "src"),)
-    res = subprocess.run([sys.executable, "-c", script],
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    res = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=300)
     assert res.returncode == 0, res.stderr[-2000:]
     assert "OK" in res.stdout
