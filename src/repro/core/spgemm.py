@@ -58,6 +58,7 @@ from repro.sparse.formats import (
 )
 from repro.sparse.partition import Partition
 from repro.sparse.updates import EdgeDelta
+from repro.trace import span
 
 # Both tiered caches speak the same get/put protocol; the engine and the
 # epoch runner accept either (mesh-sharded device tier included).
@@ -100,6 +101,7 @@ class _Prepared:
     # segment's rows) — the content half of every SegmentKey this plan
     # emits; the delta-update path preserves them for reused segments.
     fps: List[str] = dataclasses.field(default_factory=list)
+    transpose: bool = False   # streams Aᵀ (the backward direction)
 
 
 @dataclasses.dataclass
@@ -253,6 +255,17 @@ class AiresSpGEMM:
         if hit is not None:
             self._prepared[key] = hit  # re-insert: most-recently-used
             return hit
+        with span("prep"):
+            prepared = self._prepare_miss(a, plan_shape, transpose, part)
+        self._prepared[key] = prepared
+        while len(self._prepared) > self.PREPARED_CACHE_MAX:
+            self._prepared.pop(next(iter(self._prepared)))
+        return prepared
+
+    def _prepare_miss(self, a: CSR, plan_shape, transpose: bool,
+                      part: Optional[Partition]) -> _Prepared:
+        """Plan + densify one streaming direction (a `_prepare` miss)."""
+        cfg = self.config
         # The partition tiles the *streamed* orientation: forward streams
         # A's rows directly; the transposed (backward) direction only lines
         # up for square graphs, where Aᵀ's rows are the same vertex set.
@@ -260,25 +273,28 @@ class AiresSpGEMM:
         if part is not None and part.n_rows != part_rows:
             part = None
         bounds = None if part is None else part.boundaries()
-        if transpose:
-            # Plan on Aᵀ: the backward output dH is (n_cols, F), so M_C and
-            # the Eq. 7 segment budget must be sized for the transposed
-            # orientation (they differ whenever A is non-square).
-            a_t = self.transpose_of(a)
-            mem = plan_memory_unified(
-                a_t, FeatureSpec(plan_shape[0], plan_shape[1], 4, 0.0),
-                m_total=cfg.device_budget_bytes)
-            if not mem.feasible:
-                raise MemoryError(
-                    "AIRES backward plan infeasible: budget "
-                    f"{cfg.device_budget_bytes} < M_B+M_C = "
-                    f"{mem.m_b + mem.m_c:.0f}")
-            _, plan = robw_transpose_plan(a, int(mem.m_a), align=cfg.align,
-                                          a_t=a_t, boundaries=bounds)
-            stream_a = a_t
-        else:
-            mem, plan = self.plan(a, plan_shape, boundaries=bounds)
-            stream_a = a
+        with span("prep.robw"):
+            if transpose:
+                # Plan on Aᵀ: the backward output dH is (n_cols, F), so M_C
+                # and the Eq. 7 segment budget must be sized for the
+                # transposed orientation (they differ whenever A is
+                # non-square).
+                a_t = self.transpose_of(a)
+                mem = plan_memory_unified(
+                    a_t, FeatureSpec(plan_shape[0], plan_shape[1], 4, 0.0),
+                    m_total=cfg.device_budget_bytes)
+                if not mem.feasible:
+                    raise MemoryError(
+                        "AIRES backward plan infeasible: budget "
+                        f"{cfg.device_budget_bytes} < M_B+M_C = "
+                        f"{mem.m_b + mem.m_c:.0f}")
+                _, plan = robw_transpose_plan(
+                    a, int(mem.m_a), align=cfg.align, a_t=a_t,
+                    boundaries=bounds)
+                stream_a = a_t
+            else:
+                mem, plan = self.plan(a, plan_shape, boundaries=bounds)
+                stream_a = a
         # Explicit bucket ladders tag the namespace: their bricks pad
         # differently, so they must never collide with (or warm-start
         # from) the default power-of-two entries. No buckets = the
@@ -297,23 +313,22 @@ class AiresSpGEMM:
                     f":{'bwd' if transpose else 'fwd'}"
                     f":w{plan_shape[1]}:b{cfg.device_budget_bytes}"
                     f"{bucket_tag}{part_tag}")
+        with span("prep.densify"):
+            ells = list(segments_to_block_ell(stream_a, plan, bm=cfg.bm,
+                                              bk=cfg.bk,
+                                              buckets=cfg.ell_buckets))
         prepared = _Prepared(
             a=stream_a, mem=mem, plan=plan, segs=list(plan.segments),
-            ells=list(segments_to_block_ell(stream_a, plan,
-                                            bm=cfg.bm, bk=cfg.bk,
-                                            buckets=cfg.ell_buckets)),
-            cache_ns=cache_ns,
+            ells=ells, cache_ns=cache_ns,
             fps=[segment_fingerprint(stream_a, s.row_start, s.row_end)
-                 for s in plan.segments])
+                 for s in plan.segments],
+            transpose=transpose)
         if self.segment_cache is not None:
             # Pin the source graph so the id()-derived namespace can't be
             # recycled into stale hits while cached bricks live.
             self.segment_cache.pin(cache_ns, a)
         if part is not None:
             self._install_owner_map(part, prepared, transpose)
-        self._prepared[key] = prepared
-        while len(self._prepared) > self.PREPARED_CACHE_MAX:
-            self._prepared.pop(next(iter(self._prepared)))
         return prepared
 
     def _install_owner_map(self, part: Partition, prepared: _Prepared,
@@ -409,7 +424,8 @@ class AiresSpGEMM:
             # spans were re-partitioned under the same m_a.
             new_prep = _Prepared(a=stream_new, mem=prep.mem, plan=new_plan,
                                  segs=segs, ells=ells,
-                                 cache_ns=prep.cache_ns, fps=fps)
+                                 cache_ns=prep.cache_ns, fps=fps,
+                                 transpose=transpose)
             self._prepared[(csr_fingerprint(new), new.nnz, new.shape,
                             plan_shape, transpose, buckets,
                             token)] = new_prep
@@ -500,13 +516,20 @@ class AiresSpGEMM:
         return plan
 
     def _stream(self, prepared: _Prepared, consume_one: Callable,
-                feat: Optional[FeatureSpec] = None) -> tuple:
+                width: int, feat: Optional[FeatureSpec] = None) -> tuple:
         """Run one double-buffered pass over `prepared`'s segments via the
-        execute interpreter.
+        execute interpreter, under the span `aires.pass`.
 
-        consume_one(ell_dev, i) -> per-segment device result. Returns
+        consume_one(ell_dev, i) -> per-segment device result; `width` is
+        the streamed dense operand's column count. Returns
         (row-concatenated output, StreamStats).
         """
+        with span("pass", direction="bwd" if prepared.transpose else "fwd",
+                  width=width, segments=len(prepared.ells)):
+            return self._stream_pass(prepared, consume_one, feat)
+
+    def _stream_pass(self, prepared: _Prepared, consume_one: Callable,
+                     feat: Optional[FeatureSpec]) -> tuple:
         from repro.core.passes import CoalescedPayload
 
         cfg = self.config
@@ -557,6 +580,7 @@ class AiresSpGEMM:
             stats.ici_bytes = after.ici_bytes - before.ici_bytes
             stats.directory_hit_bytes = (
                 after.directory_hit_bytes - before.directory_hit_bytes)
+            stats.demoted_bytes = after.demoted_bytes - before.demoted_bytes
         # Flatten coalesced-group results back into per-segment plan order.
         flat = []
         for p in parts:
@@ -564,8 +588,9 @@ class AiresSpGEMM:
                 flat.extend(p)
             else:
                 flat.append(p)
-        out = jnp.concatenate(
-            [p[: s.n_rows] for p, s in zip(flat, prepared.segs)], axis=0)
+        with span("assemble"):
+            out = jnp.concatenate(
+                [p[: s.n_rows] for p, s in zip(flat, prepared.segs)], axis=0)
         return out, stats
 
     def _stream_spmm(self, prepared: _Prepared, dense) -> tuple:
@@ -579,7 +604,7 @@ class AiresSpGEMM:
             prepared,
             lambda ell_dev, i: bcsr_spmm(ell_dev, dense_dev,
                                          interpret=cfg.interpret),
-            feat=feat)
+            int(dense.shape[1]), feat=feat)
 
     # ---- differentiable public API --------------------------------------
 
@@ -642,7 +667,8 @@ class AiresSpGEMM:
             y, stats = self._stream(
                 fwd,
                 lambda ell_dev, i: fused_gcn_layer(
-                    ell_dev, h_dev, w_in, b_in, interpret=cfg.interpret))
+                    ell_dev, h_dev, w_in, b_in, interpret=cfg.interpret),
+                int(h_in.shape[1]))
             self.last_stream_stats = stats
             self.forward_stats_log.append(stats)
             return y

@@ -25,6 +25,7 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, Hashable, Optional, Tuple
 
 from repro.io.tiers import MemoryTier, Path, TieredMemorySystem
+from repro.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,10 +281,6 @@ class TieredSegmentCache:
         self._pins: Dict[Hashable, Any] = {}
         self._lock = threading.Lock()
         self.stats = CacheStats()
-        # Convenience mirror of the last get()'s promotion seconds. NOT
-        # race-free across threads — concurrent callers should use
-        # get_with_cost() instead.
-        self.last_get_transfer_s: float = 0.0
 
     # ---- introspection ---------------------------------------------------
 
@@ -396,9 +393,8 @@ class TieredSegmentCache:
                       tms: Optional[TieredMemorySystem] = None):
         """Like get(), but returns (value, transfer_seconds): the modeled
         cost of the promotion this lookup triggered (0.0 for a device-tier
-        hit or a miss). Race-free, unlike reading last_get_transfer_s."""
+        hit or a miss)."""
         with self._lock:
-            self.last_get_transfer_s = 0.0
             entry = self._device.get(key)
             if entry is not None:
                 self._device.move_to_end(key)
@@ -411,11 +407,11 @@ class TieredSegmentCache:
                 if self.directory is not None:
                     # Our host copy is consumed by the promotion.
                     self.directory.unpublish(key, self.worker_id)
-                value = self._promote(entry.value)
+                with span("cache.promote", bytes=entry.nbytes):
+                    value = self._promote(entry.value)
                 cost = self._charge(
                     tms, MemoryTier.HOST, MemoryTier.DEVICE, entry.nbytes,
                     "cache/promote")
-                self.last_get_transfer_s = cost
                 self.stats.promoted_bytes += entry.nbytes
                 self.stats.host_hits += 1
                 self.stats.hit_bytes += nbytes
@@ -429,11 +425,11 @@ class TieredSegmentCache:
                     # of a fresh wire upload. The peer keeps its host copy
                     # (and stays the directory holder).
                     host_value, _, host_nbytes = fetched
-                    value = self._promote(host_value)
+                    with span("cache.promote", bytes=host_nbytes):
+                        value = self._promote(host_value)
                     cost = self._charge(
                         tms, MemoryTier.HOST, MemoryTier.DEVICE, host_nbytes,
                         "cache/peer-promote")
-                    self.last_get_transfer_s = cost
                     self.stats.promoted_bytes += host_nbytes
                     self.stats.directory_hits += 1
                     self.stats.directory_hit_bytes += nbytes
@@ -557,7 +553,8 @@ class TieredSegmentCache:
         self._charge(tms, MemoryTier.DEVICE, MemoryTier.HOST,
                      entry.nbytes, "cache/demote")
         self.stats.demoted_bytes += entry.nbytes
-        entry = _Entry(self._demote(entry.value), entry.nbytes)
+        with span("cache.demote", bytes=entry.nbytes):
+            entry = _Entry(self._demote(entry.value), entry.nbytes)
         if self.host_budget_bytes is not None:
             while self._host_used + entry.nbytes > self.host_budget_bytes:
                 victim_key, dropped = self._host.popitem(last=False)

@@ -173,7 +173,6 @@ class ShardedSegmentCache:
         # the mesh); the aggregate `stats` property folds it in.
         self._remote_hits = 0
         self._ici_bytes = 0
-        self.last_get_transfer_s: float = 0.0
         # Placement overrides (the owner map): keys whose owner differs
         # from the default owner because a put() carried an explicit shard
         # — the shard-placement rewrite pass pins a graph's hot bricks to
@@ -401,7 +400,6 @@ class ShardedSegmentCache:
             cost += self._charge_ici(tms, nbytes, "cache/ici", hops=hops)
             if self.devices is not None:
                 value = _place(value, self.devices[self.local_shard])
-        self.last_get_transfer_s = cost
         return value, cost
 
     def peek_cost(self, key: SegmentKey, nbytes: int = 0,

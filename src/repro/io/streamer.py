@@ -15,12 +15,12 @@ from typing import Any, Callable, Iterable, Iterator, List, Optional
 
 import jax
 
+from repro.trace import span
+
 
 @dataclasses.dataclass
 class StreamStats:
     segments: int = 0
-    put_seconds: float = 0.0       # wall time blocked on device_put dispatch
-    compute_seconds: float = 0.0   # wall time blocked on result readiness
     reissues: int = 0              # straggler mitigations
     uploaded_bytes: int = 0        # wire bytes (when payload_nbytes is given)
     cache_hits: int = 0            # segments served from the segment cache
@@ -33,6 +33,8 @@ class StreamStats:
     #                                shard placements) during this stream
     directory_hit_bytes: int = 0   # wire bytes served from a peer worker's
     #                                host copy via the CacheDirectory
+    demoted_bytes: int = 0         # bytes the cache copied device->host
+    #                                (demotions) during this stream
 
 
 class DoubleBufferedStreamer:
@@ -77,22 +79,20 @@ class DoubleBufferedStreamer:
         self.cache_store = cache_store
         self.stats = StreamStats()
 
-    def _upload_with_deadline(self, payload: Any) -> Any:
+    def _upload_with_deadline(self, payload: Any, segment: int) -> Any:
         nbytes = (int(self.payload_nbytes(payload))
                   if self.payload_nbytes is not None else 0)
         if self.cache_lookup is not None:
-            t0 = time.perf_counter()
-            cached = self.cache_lookup(payload)
+            with span("cache.probe", segment=segment):
+                cached = self.cache_lookup(payload)
             if cached is not None:
-                # Lookup cost includes any host->device promotion the cache
-                # performed — that is real transfer time, count it.
-                self.stats.put_seconds += time.perf_counter() - t0
                 self.stats.cache_hits += 1
                 self.stats.cache_hit_bytes += nbytes
                 return cached
         self.stats.uploaded_bytes += nbytes
         t0 = time.perf_counter()
-        dev = self.upload(payload)
+        with span("upload", segment=segment, bytes=nbytes):
+            dev = self.upload(payload)
         if self.deadline_s is not None:
             for _ in range(self.max_reissue):
                 if time.perf_counter() - t0 <= self.deadline_s:
@@ -102,32 +102,31 @@ class DoubleBufferedStreamer:
                 self.stats.reissues += 1
                 self.stats.uploaded_bytes += nbytes
                 t0 = time.perf_counter()
-                dev = self.upload(payload)
-        self.stats.put_seconds += time.perf_counter() - t0
+                with span("upload", segment=segment, bytes=nbytes):
+                    dev = self.upload(payload)
         if self.cache_store is not None:
-            self.cache_store(payload, dev)
+            with span("cache.store", segment=segment):
+                self.cache_store(payload, dev)
         return dev
 
     def run(self, payloads: Iterable[Any]) -> Iterator[Any]:
         """Yield consume() results in order, depth-deep pipelined."""
-        it = iter(payloads)
+        it = enumerate(payloads)
         inflight: List[Any] = []
         # Prime the pipeline.
-        for payload in it:
-            inflight.append(self._upload_with_deadline(payload))
+        for j, payload in it:
+            inflight.append(self._upload_with_deadline(payload, j))
             if len(inflight) >= self.depth:
                 break
         i = 0
         while inflight:
             dev = inflight.pop(0)
-            t0 = time.perf_counter()
             result = self.consume(dev, i)
-            self.stats.compute_seconds += time.perf_counter() - t0
             self.stats.segments += 1
             # Refill the pipeline before blocking on the result.
             try:
-                nxt = next(it)
-                inflight.append(self._upload_with_deadline(nxt))
+                j, nxt = next(it)
+                inflight.append(self._upload_with_deadline(nxt, j))
             except StopIteration:
                 pass
             yield result
@@ -136,5 +135,6 @@ class DoubleBufferedStreamer:
     def run_all(self, payloads: Iterable[Any]) -> List[Any]:
         out = list(self.run(payloads))
         # Block once at the end (paper Phase III store) rather than per segment.
-        jax.block_until_ready([o for o in out if o is not None])
+        with span("pass.wait"):
+            jax.block_until_ready([o for o in out if o is not None])
         return out

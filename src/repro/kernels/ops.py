@@ -16,6 +16,7 @@ from repro.kernels import bcsr_spmm as _bcsr
 from repro.kernels import decode_attn as _dec
 from repro.kernels import flash_attn as _flash
 from repro.sparse.formats import BlockELL
+from repro.trace import span
 
 
 def _on_cpu() -> bool:
@@ -32,6 +33,13 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
     return jnp.pad(x, pads)
 
 
+def _needed_rows(ell: BlockELL) -> int:
+    """Rows of H the segment's column tiles reach. Reading the largest
+    tile of a device brick waits for its upload."""
+    with span("kernel.sync"):
+        return int(np.max(ell.col_tile, initial=0) + 1) * ell.bk
+
+
 def bcsr_spmm(
     ell: BlockELL,
     h: jax.Array,
@@ -46,26 +54,27 @@ def bcsr_spmm(
     """
     if interpret is None:
         interpret = _on_cpu()
-    f = h.shape[1]
-    bn = min(bn, ((f + 127) // 128) * 128)
-    h_pad = _pad_to(_pad_to(jnp.asarray(h), 0, ell.bk), 1, bn)
-    # Segment column coverage may exceed h rows when A is wider than H rows
-    # (never in GCN aggregation: A is n×n, H is n×f).
-    need_k = int(np.max(ell.col_tile, initial=0) + 1) * ell.bk
-    if h_pad.shape[0] < need_k:
-        h_pad = jnp.pad(h_pad, ((0, need_k - h_pad.shape[0]), (0, 0)))
-    out = _bcsr.bcsr_spmm_pallas(
-        jnp.asarray(ell.blocks),
-        jnp.asarray(ell.col_tile),
-        jnp.asarray(ell.n_tiles),
-        h_pad,
-        bm=ell.bm,
-        bk=ell.bk,
-        bn=bn,
-        interpret=interpret,
-        out_dtype=out_dtype,
-    )
-    return out[: ell.n_rows, :f]
+    with span("kernel", rows=ell.n_rows):
+        f = h.shape[1]
+        bn = min(bn, ((f + 127) // 128) * 128)
+        h_pad = _pad_to(_pad_to(jnp.asarray(h), 0, ell.bk), 1, bn)
+        # Segment column coverage may exceed h rows when A is wider than H
+        # rows (never in GCN aggregation: A is n×n, H is n×f).
+        need_k = _needed_rows(ell)
+        if h_pad.shape[0] < need_k:
+            h_pad = jnp.pad(h_pad, ((0, need_k - h_pad.shape[0]), (0, 0)))
+        out = _bcsr.bcsr_spmm_pallas(
+            jnp.asarray(ell.blocks),
+            jnp.asarray(ell.col_tile),
+            jnp.asarray(ell.n_tiles),
+            h_pad,
+            bm=ell.bm,
+            bk=ell.bk,
+            bn=bn,
+            interpret=interpret,
+            out_dtype=out_dtype,
+        )
+        return out[: ell.n_rows, :f]
 
 
 def fused_gcn_layer(
@@ -81,23 +90,24 @@ def fused_gcn_layer(
     materializing X in HBM."""
     if interpret is None:
         interpret = _on_cpu()
-    h_pad = _pad_to(jnp.asarray(h), 0, ell.bk)
-    need_k = int(np.max(ell.col_tile, initial=0) + 1) * ell.bk
-    if h_pad.shape[0] < need_k:
-        h_pad = jnp.pad(h_pad, ((0, need_k - h_pad.shape[0]), (0, 0)))
-    out = _bcsr.fused_gcn_layer_pallas(
-        jnp.asarray(ell.blocks),
-        jnp.asarray(ell.col_tile),
-        jnp.asarray(ell.n_tiles),
-        h_pad,
-        jnp.asarray(w),
-        jnp.asarray(b),
-        bm=ell.bm,
-        bk=ell.bk,
-        interpret=interpret,
-        out_dtype=out_dtype,
-    )
-    return out[: ell.n_rows]
+    with span("kernel", rows=ell.n_rows):
+        h_pad = _pad_to(jnp.asarray(h), 0, ell.bk)
+        need_k = _needed_rows(ell)
+        if h_pad.shape[0] < need_k:
+            h_pad = jnp.pad(h_pad, ((0, need_k - h_pad.shape[0]), (0, 0)))
+        out = _bcsr.fused_gcn_layer_pallas(
+            jnp.asarray(ell.blocks),
+            jnp.asarray(ell.col_tile),
+            jnp.asarray(ell.n_tiles),
+            h_pad,
+            jnp.asarray(w),
+            jnp.asarray(b),
+            bm=ell.bm,
+            bk=ell.bk,
+            interpret=interpret,
+            out_dtype=out_dtype,
+        )
+        return out[: ell.n_rows]
 
 
 def decode_attention(

@@ -62,6 +62,7 @@ from repro.io.tiers import (
 from repro.sparse.formats import CSR, BlockELL
 from repro.sparse.partition import Partition, partition_graph
 from repro.sparse.updates import EdgeDelta, apply_edge_updates
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -273,6 +274,7 @@ class GroupStats:
     promoted_bytes: int = 0
     ici_bytes: int = 0
     directory_hit_bytes: int = 0
+    demoted_bytes: int = 0
     segments_streamed: int = 0
     aggregation_passes: int = 0
 
@@ -283,6 +285,7 @@ class GroupStats:
         self.promoted_bytes += stats.promoted_bytes
         self.ici_bytes += stats.ici_bytes
         self.directory_hit_bytes += stats.directory_hit_bytes
+        self.demoted_bytes += stats.demoted_bytes
         self.segments_streamed += stats.segments
         self.aggregation_passes += 1
 
@@ -312,6 +315,9 @@ class BatchReport:
     # holds the brick. 0 with no directory attached.
     directory_hit_bytes: int = 0
     duplicate_avoided_bytes: int = 0
+    # Bytes the segment cache copied device->host (demotions) while this
+    # batch streamed.
+    demoted_bytes: int = 0
     # Admission control: requests rejected at submit() since the previous
     # report, and queued requests whose deadline expired before this batch
     # ran them.
@@ -987,6 +993,7 @@ class ServingEngine:
             ici_bytes=totals.ici_bytes,
             directory_hit_bytes=totals.directory_hit_bytes,
             duplicate_avoided_bytes=dup,
+            demoted_bytes=totals.demoted_bytes,
             rejected=rejected, expired=expired, request_latency=latency)
 
     def serve_group(self, name: str, group: List[InferenceRequest],
@@ -996,15 +1003,21 @@ class ServingEngine:
         request id — `(since_batch_t0, since_group_start)` wall seconds,
         taken when each request's output materializes on host — and the
         group's `GroupStats` byte accounting)."""
+        with span("engine.group", graph=name, requests=len(group)):
+            return self._serve_group(name, group, t0)
+
+    def _serve_group(self, name: str, group: List[InferenceRequest],
+                     t0: float) -> tuple:
         a = self._graphs[name]
         eng = self._engines[name]
         mark = len(eng.forward_stats_log)
         g0 = time.perf_counter()
         # Per-request device-resident state: (request, activation, next layer).
-        acts = [jnp.asarray(np.asarray(r.features, dtype=np.float32))
-                for r in group]
-        wss = [[jnp.asarray(np.asarray(w, dtype=np.float32)) for w in r.weights]
-               for r in group]
+        with span("engine.inputs"):
+            acts = [jnp.asarray(np.asarray(r.features, dtype=np.float32))
+                    for r in group]
+            wss = [[jnp.asarray(np.asarray(w, dtype=np.float32))
+                    for w in r.weights] for r in group]
         n_aggs = [max(len(ws), 1) for ws in wss]
         outputs: Dict[int, np.ndarray] = {}
         done_s: Dict[int, tuple] = {}
@@ -1014,20 +1027,23 @@ class ServingEngine:
                 eng, a, [acts[i] for i in live])
             for i, x in zip(live, aggregated):
                 ws = wss[i]
+                rid = group[i].request_id
                 if layer < len(ws):
                     # Requests are float32: combine at full float32, not
                     # the TPU's default single bfloat16 pass.
-                    h = jnp.dot(x, ws[layer],
-                                precision=jax.lax.Precision.HIGHEST)
-                    if layer < len(ws) - 1:
-                        h = jnp.maximum(h, 0.0)   # relu between layers
+                    with span("engine.combine", request=rid):
+                        h = jnp.dot(x, ws[layer],
+                                    precision=jax.lax.Precision.HIGHEST)
+                        if layer < len(ws) - 1:
+                            h = jnp.maximum(h, 0.0)   # relu between layers
                 else:                             # bare aggregation request
                     h = x
                 acts[i] = h
                 if layer == n_aggs[i] - 1:
-                    outputs[i] = np.asarray(h)
+                    with span("engine.readback", request=rid):
+                        outputs[i] = np.asarray(h)
                     now = time.perf_counter()
-                    done_s[group[i].request_id] = (now - t0, now - g0)
+                    done_s[rid] = (now - t0, now - g0)
         results = [InferenceResult(group[i].request_id, name, outputs[i])
                    for i in range(len(group))]
         stats = GroupStats()

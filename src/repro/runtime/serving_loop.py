@@ -504,6 +504,7 @@ def replay_round(engine: ServingEngine, trace: Sequence[Arrival],
             promoted_bytes=report.promoted_bytes,
             ici_bytes=report.ici_bytes,
             directory_hit_bytes=report.directory_hit_bytes,
+            demoted_bytes=report.demoted_bytes,
             segments_streamed=report.segments_streamed,
             aggregation_passes=report.aggregation_passes))
         clock.advance_to(t)

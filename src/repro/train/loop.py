@@ -26,6 +26,7 @@ from repro.models.config import ArchConfig
 from repro.models.transformer import lm_loss
 from repro.train.compression import compress_grads, decompress_grads, ef_init
 from repro.train.optim import make_optimizer
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -102,9 +103,12 @@ def make_gcn_train_step(cfg, engine, a, h0, labels,
     init_opt, opt_update = _mk(optimizer, lr=lr, **opt_kwargs)
 
     def step(params, opt_state):
-        loss, grads = jax.value_and_grad(
-            lambda p: gcn_loss(cfg, p, a, h0, labels, engine=engine))(params)
-        params, opt_state = opt_update(params, grads, opt_state)
+        with span("train.step"):
+            loss, grads = jax.value_and_grad(
+                lambda p: gcn_loss(cfg, p, a, h0, labels,
+                                   engine=engine))(params)
+            with span("train.update"):
+                params, opt_state = opt_update(params, grads, opt_state)
         return loss, params, opt_state
 
     return init_opt, step

@@ -79,13 +79,14 @@ def test_transfers_charged_through_tiered_memory_system():
     cache.put(_key(0), "a", 1)
     cache.put(_key(1), "b", 1)
     cache.put(_key(2), "c", 1)              # one demotion
-    cache.get(_key(0), nbytes=1)            # promotion (+ a demotion: full)
+    _, cost = cache.get_with_cost(_key(0), nbytes=1)  # promotion (+ a
+    #                                                     demotion: full)
     tags = [t.tag for t in tms.transfers]
     assert tags == ["cache/demote", "cache/promote", "cache/demote"]
-    assert cache.last_get_transfer_s > 0.0
+    assert cost > 0.0
     n_before = len(tms.transfers)
-    cache.get(_key(0), nbytes=1)            # device hit: free
-    assert cache.last_get_transfer_s == 0.0
+    _, cost = cache.get_with_cost(_key(0), nbytes=1)  # device hit: free
+    assert cost == 0.0
     assert len(tms.transfers) == n_before
 
 
