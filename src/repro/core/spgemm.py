@@ -520,7 +520,8 @@ class AiresSpGEMM:
         """Run one double-buffered pass over `prepared`'s segments via the
         execute interpreter, under the span `aires.pass`.
 
-        consume_one(ell_dev, i) -> per-segment device result; `width` is
+        consume_one(ell_dev, ell) -> per-segment device result, from the
+        segment's device arrays and its host BlockELL; `width` is
         the streamed dense operand's column count. Returns
         (row-concatenated output, StreamStats).
         """
@@ -551,7 +552,7 @@ class AiresSpGEMM:
             blocks, col_tile, n_tiles, ell = dev_payload
             ell_dev = dataclasses.replace(
                 ell, blocks=blocks, col_tile=col_tile, n_tiles=n_tiles)
-            return consume_one(ell_dev, i)
+            return consume_one(ell_dev, ell)
 
         def consume(dev_payload, i):
             if isinstance(dev_payload, CoalescedPayload):
@@ -602,8 +603,9 @@ class AiresSpGEMM:
         feat = FeatureSpec(int(dense.shape[0]), int(dense.shape[1]), 4, 0.0)
         return self._stream(
             prepared,
-            lambda ell_dev, i: bcsr_spmm(ell_dev, dense_dev,
-                                         interpret=cfg.interpret),
+            lambda ell_dev, ell: bcsr_spmm(
+                ell_dev, dense_dev, interpret=cfg.interpret,
+                bricks=int(ell.n_tiles.sum())),
             int(dense.shape[1]), feat=feat)
 
     # ---- differentiable public API --------------------------------------
@@ -666,7 +668,7 @@ class AiresSpGEMM:
             h_dev = jax.device_put(h_in)
             y, stats = self._stream(
                 fwd,
-                lambda ell_dev, i: fused_gcn_layer(
+                lambda ell_dev, ell: fused_gcn_layer(
                     ell_dev, h_dev, w_in, b_in, interpret=cfg.interpret),
                 int(h_in.shape[1]))
             self.last_stream_stats = stats

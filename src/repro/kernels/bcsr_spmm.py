@@ -1,40 +1,49 @@
 """Block-ELL × dense SpMM — the paper's tiled compressed matmul on TPU.
 
-X[rb*bm:(rb+1)*bm, ft*bn:(ft+1)*bn] = Σ_s blocks[rb, s] @ H[col_tile[rb, s]]
+X[rb*bm:(rb+1)*bm, :] = Σ_{s < n_tiles[rb]} blocks[rb, s] @ H[col_tile[rb, s]]
 
-Grid (row_blocks, n_feat_tiles, ell_width); the reduction dim s is
-innermost so the output block is revisited and accumulated in place (TPU
-'arbitrary' dimension semantics compatible). Tile indices are scalar-
-prefetched so the H BlockSpec can route each grid step's HBM→VMEM DMA to the
-right column tile — this is the TPU replacement for the CUDA gather loop.
+One grid step covers a group of `group` row blocks at the full padded
+feature width, and walks only their populated ELL slots. The group's bricks
+come by BlockSpec; H stays in HBM and each referenced (bk, F_pad) tile is
+copied into a VMEM ring of `RING` tiles, the copies for the next slots in
+flight while the current brick is multiplied. A padded slot costs no copy
+and no arithmetic. The group size follows from the shapes so that a step's
+buffers fit `VMEM_STEP_BYTES`: Mosaic lays an (8, 8) f32 brick out in an
+(8, 128) tile, 16x its bytes, so a wide ELL leaves room for few row blocks.
+Where one row block's bricks do not fit, the step takes one row block and a
+chunk of its slots, and a second grid axis walks the chunks.
 
 Scalar-prefetch operands live in SMEM, which holds 1 MiB on v5e and pads
 the last dimension of a 2-D operand to 128 lanes. `col_tile` is therefore
 prefetched flat (index rb*ell_w + s), and a segment whose tile table does
 not fit `SMEM_PREFETCH_WORDS` is covered by several calls of one kernel,
 each over a slice of `smem_rows_per_call` row blocks. Slicing the bricks
-too keeps each call's operands small: Mosaic lays an (8, 8) f32 brick out
-in (8, 128) lane tiles, so a call's bricks take 16x their bytes on the
-device while it runs.
+too keeps each call's operands small: a call's bricks take 16x their bytes
+on the device while it runs.
 
-Padded ELL slots (col_tile == -1) are skipped with @pl.when; their DMA is
-routed to tile 0 (harmless read) and contributes nothing.
-
-Brick products run at `Precision.HIGHEST` (full float32 on the MXU) and
-accumulate in float32, so the kernel matches a float32 reference on the
-chip as it does in interpret mode.
+Brick products are exact float32 multiply-adds on the VPU, accumulated in
+float32 over the slots in ascending order, so the kernel matches a float32
+reference on the chip as it does in interpret mode.
 """
 from __future__ import annotations
 
 import functools
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # int32 words one call may scalar-prefetch (col_tile + n_tiles):
 # a quarter of v5e's 1 MiB SMEM, leaving the rest to Mosaic's own scalars.
 SMEM_PREFETCH_WORDS = 1 << 16
+# VMEM bytes one grid step's buffers may take: half of v5e's default
+# scoped limit (16 MiB), the rest left to Mosaic.
+VMEM_STEP_BYTES = 8 << 20
+# H tiles in the copy ring: one being multiplied, the rest in flight.
+RING = 16
 
 
 def smem_rows_per_call(n_rb: int, ell_w: int) -> int:
@@ -42,50 +51,146 @@ def smem_rows_per_call(n_rb: int, ell_w: int) -> int:
     return max(1, min(n_rb, (SMEM_PREFETCH_WORDS - 1) // (ell_w + 1)))
 
 
-def _spmm_kernel(n_tiles_ref, col_tile_ref, a_ref, h_ref, o_ref):
-    rb = pl.program_id(0)
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(s < n_tiles_ref[rb])
-    def _acc():
-        o_ref[...] += jnp.dot(
-            a_ref[0, 0], h_ref[...], preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        ).astype(o_ref.dtype)
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes of an array in VMEM, its last two dims padded to whole
+    (sublane, 128) tiles: 8 sublanes for 32-bit types, 16 for 16-bit."""
+    item = np.dtype(dtype).itemsize
+    sub = 32 // item
+    return (math.prod(shape[:-2]) * -(-shape[-2] // sub) * sub
+            * -(-shape[-1] // 128) * 128 * item)
 
 
-def _split_spmm(blocks, col_tile, n_tiles, h, *, bm: int, bk: int, bn: int,
-                interpret: bool, out_dtype, rows: int) -> jax.Array:
-    """The segment's SpMM as calls of one kernel over `rows` row blocks each."""
+def step_shape(rows: int, ell_w: int, bm: int, bk: int, f_pad: int,
+               a_dtype, h_dtype, out_dtype) -> tuple:
+    """(group, chunk): row blocks and ELL slots one grid step covers.
+
+    The pipeline double-buffers the step's bricks and output block; the
+    ring holds `RING` tiles of H. A group takes all `ell_w` slots of as
+    many row blocks as fit; where not even one row block's do, the step
+    takes one row block and as many slots as fit."""
+    room = VMEM_STEP_BYTES - RING * _vmem_bytes((bk, f_pad), h_dtype)
+    out_row = 2 * _vmem_bytes((bm, f_pad), out_dtype)
+    brick = 2 * _vmem_bytes((bm, bk), a_dtype)
+    if out_row + ell_w * brick <= room:
+        return max(1, min(rows, room // (out_row + ell_w * brick))), ell_w
+    return 1, max(1, (room - out_row) // brick)
+
+
+def grid_steps(n_rb: int, ell_w: int, bm: int, bk: int, f_pad: int,
+               a_dtype, h_dtype, out_dtype=jnp.float32) -> int:
+    """Grid steps `bcsr_spmm_pallas` runs for a segment of these shapes."""
+    rows = smem_rows_per_call(n_rb, ell_w)
+    group, chunk = step_shape(rows, ell_w, bm, bk, f_pad, a_dtype, h_dtype,
+                              out_dtype)
+    return -(-n_rb // rows) * -(-rows // group) * -(-ell_w // chunk)
+
+
+def _brick_product(acc, a, h):
+    """acc + a @ h as float32 multiply-adds over a's columns in order."""
+    a = a.astype(jnp.float32)
+    h = h.astype(jnp.float32)
+    for j in range(a.shape[1]):
+        acc = acc + a[:, j:j + 1] * h[j:j + 1, :]
+    return acc
+
+
+def _spmm_kernel(n_tiles_ref, col_ref, a_ref, h_ref, o_ref, ring, sems,
+                 cols, *, rows: int, group: int, chunk: int, ell_w: int,
+                 bm: int, bk: int):
+    g, c = pl.program_id(0), pl.program_id(1)
+    first = g * group                       # the group's first row block
+    n_rows = jnp.minimum(group, rows - first)   # fewer in the tail group
+    lo = c * chunk                          # the chunk's first slot
+    depth = ring.shape[0]
+
+    def end(r):
+        """One past row block r's last populated slot in this chunk."""
+        return jnp.clip(n_tiles_ref[first + r], lo, lo + chunk)
+
+    # The column tiles of the step's populated slots, in the order walked.
+    def list_row(r, k):
+        def one(s, k):
+            cols[k] = col_ref[(first + r) * ell_w + s]
+            return k + 1
+        return jax.lax.fori_loop(lo, end(r), one, k)
+
+    total = jax.lax.fori_loop(0, n_rows, list_row, 0)
+
+    def copy(k):
+        slot = k % depth
+        return pltpu.make_async_copy(h_ref.at[pl.ds(cols[k] * bk, bk)],
+                                     ring.at[slot], sems.at[slot])
+
+    def start(k):
+        @pl.when(k < total)
+        def _():
+            copy(k).start()
+
+    for k in range(depth - 1):
+        start(k)
+
+    def row(r, k):
+        def walk(s, carry):
+            acc, k = carry
+            start(k + depth - 1)
+            copy(k).wait()
+            acc = _brick_product(acc, a_ref[r, s - lo], ring[k % depth])
+            return acc, k + 1
+
+        at = pl.ds(pl.multiple_of(r * bm, bm), bm)
+        acc = jnp.zeros((bm, o_ref.shape[1]), jnp.float32)
+        if chunk < ell_w:   # a later chunk adds to what the earlier wrote
+            acc = jnp.where(c == 0, acc, o_ref[at, :].astype(jnp.float32))
+        acc, k = jax.lax.fori_loop(lo, end(r), walk, (acc, k))
+        o_ref[at, :] = acc.astype(o_ref.dtype)
+        return k
+
+    jax.lax.fori_loop(0, n_rows, row, 0)
+
+
+def _split_spmm(blocks, col_tile, n_tiles, h, *, bm: int, bk: int,
+                interpret: bool, out_dtype, rows: int, group: int = 0,
+                chunk: int = 0) -> jax.Array:
+    """The segment's SpMM as calls of one kernel over `rows` row blocks
+    each; `group` and `chunk` (0: from `step_shape`) shape its steps."""
     n_rb, ell_w = blocks.shape[0], blocks.shape[1]
     f_pad = h.shape[1]
     rows = min(rows, n_rb)
+    auto = step_shape(rows, ell_w, bm, bk, f_pad, blocks.dtype, h.dtype,
+                      out_dtype)
+    group = min(group or auto[0], rows)
+    chunk = min(chunk or auto[1], ell_w)
     col_flat = col_tile.reshape(n_rb * ell_w)
 
-    def h_index_map(rb, ft, s, n_tiles_ref, col_tile_ref):
-        # Route the DMA to the referenced column tile; padded slots read
-        # tile 0.
-        return (jnp.maximum(col_tile_ref[rb * ell_w + s], 0), ft)
+    def brick_index(g, c, n_tiles_ref, col_ref):
+        if group > 1:
+            return g, c, 0, 0
+        # Chunks past the row block's last populated slot keep the block
+        # already in VMEM, so they cost no copy.
+        last = jnp.maximum(n_tiles_ref[g] - 1, 0) // chunk
+        return g, jnp.minimum(c, last), 0, 0
 
     call = pl.pallas_call(
-        _spmm_kernel,
+        functools.partial(_spmm_kernel, rows=rows, group=group, chunk=chunk,
+                          ell_w=ell_w, bm=bm, bk=bk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows, f_pad // bn, ell_w),
+            grid=(pl.cdiv(rows, group), pl.cdiv(ell_w, chunk)),
             in_specs=[
-                pl.BlockSpec((1, 1, bm, bk),
-                             lambda rb, ft, s, *_: (rb, s, 0, 0)),
-                pl.BlockSpec((bk, bn), h_index_map),
+                pl.BlockSpec((group, chunk, bm, bk), brick_index),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((bm, bn), lambda rb, ft, s, *_: (rb, ft)),
+            out_specs=pl.BlockSpec((group * bm, f_pad),
+                                   lambda g, c, *_: (g, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((RING, bk, f_pad), h.dtype),
+                pltpu.SemaphoreType.DMA((RING,)),
+                pltpu.SMEM((group * chunk,), jnp.int32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((rows * bm, f_pad), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )
@@ -125,8 +230,11 @@ def bcsr_spmm_pallas(
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> jax.Array:
+    """X = A @ H for one segment; each step takes H's F_pad columns whole
+    (`bn` is the unit `h` was padded to)."""
+    del bn
     n_rb, ell_w = blocks.shape[0], blocks.shape[1]
-    return _split_spmm(blocks, col_tile, n_tiles, h, bm=bm, bk=bk, bn=bn,
+    return _split_spmm(blocks, col_tile, n_tiles, h, bm=bm, bk=bk,
                        interpret=interpret, out_dtype=out_dtype,
                        rows=smem_rows_per_call(n_rb, ell_w))
 

@@ -47,17 +47,30 @@ def bcsr_spmm(
     bn: int = 128,
     interpret: Optional[bool] = None,
     out_dtype=jnp.float32,
+    bricks: Optional[int] = None,
 ) -> jax.Array:
     """X = A @ H for a BlockELL segment of A and dense H (n_cols, F).
 
-    Returns (ell.n_rows, F) — padding rows/cols are stripped.
+    Returns (ell.n_rows, F) — padding rows/cols are stripped. The span
+    `aires.kernel` carries the kernel's `grid_steps` and `bricks`, the
+    populated slots it walks: given, or summed from `ell.n_tiles` where
+    that is a host array (never read back from the device).
     """
     if interpret is None:
         interpret = _on_cpu()
-    with span("kernel", rows=ell.n_rows):
-        f = h.shape[1]
-        bn = min(bn, ((f + 127) // 128) * 128)
-        h_pad = _pad_to(_pad_to(jnp.asarray(h), 0, ell.bk), 1, bn)
+    if bricks is None and isinstance(ell.n_tiles, np.ndarray):
+        bricks = int(ell.n_tiles.sum())
+    h = jnp.asarray(h)
+    f = h.shape[1]
+    bn = min(bn, ((f + 127) // 128) * 128)
+    f_pad = -(-f // bn) * bn
+    counts = dict(grid_steps=_bcsr.grid_steps(
+        ell.n_row_blocks, ell.ell_width, ell.bm, ell.bk, f_pad,
+        jax.dtypes.canonicalize_dtype(ell.blocks.dtype), h.dtype, out_dtype))
+    if bricks is not None:
+        counts["bricks"] = bricks
+    with span("kernel", rows=ell.n_rows, **counts):
+        h_pad = _pad_to(_pad_to(h, 0, ell.bk), 1, bn)
         # Segment column coverage may exceed h rows when A is wider than H
         # rows (never in GCN aggregation: A is n×n, H is n×f).
         need_k = _needed_rows(ell)

@@ -16,7 +16,8 @@ nest, the second named is the inner:
   aires.cache.promote     a host-tier (or peer) brick put back on device
   aires.cache.store       the segment cache insert after an upload
   aires.cache.demote      a device brick copied down to the host tier
-  aires.kernel            host dispatch of one segment's Pallas kernel
+  aires.kernel            host dispatch of one segment's Pallas kernel,
+                          with its grid_steps and the bricks it walks
   aires.kernel.sync       its read of the brick's largest column tile
   aires.engine.group      `ServingEngine.serve_group`
   aires.engine.inputs     the requests' features and weights to device

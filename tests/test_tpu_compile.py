@@ -62,11 +62,14 @@ def no_persistent_cache():
 # and transposed segments chip_smoke.py streams at its default scale (1e-2,
 # seed 0: 550,400 rows; it prints them on its early lines). A 2-D
 # (4096, 16) tile table, padded to 128 SMEM lanes, needs 2 MiB of v5e's
-# 1 MiB SMEM: the kernel must split such a segment over several calls.
+# 1 MiB SMEM: the kernel must split such a segment over several calls. One
+# row block of a 4096-wide ELL takes 32 MiB of double-buffered bricks in
+# VMEM, twice v5e's scoped limit: a grid step must take a chunk of its slots.
 SEGMENTS = {
     "smoke-forward": (13784, 64, 550400),
     "smoke-transposed": (13776, 64, 550400),
     "smem-4096x16": (4096, 16, 32768),
+    "wide-64x4096": (64, 4096, 32768),
 }
 
 
