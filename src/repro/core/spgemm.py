@@ -520,8 +520,9 @@ class AiresSpGEMM:
         """Run one double-buffered pass over `prepared`'s segments via the
         execute interpreter, under the span `aires.pass`.
 
-        consume_one(ell_dev, ell) -> per-segment device result, from the
-        segment's device arrays and its host BlockELL; `width` is
+        consume_one(ell_dev, ell, i) -> per-segment device result, from
+        the segment's device arrays, its host BlockELL and its index in
+        the plan; `width` is
         the streamed dense operand's column count. Returns
         (row-concatenated output, StreamStats).
         """
@@ -552,7 +553,7 @@ class AiresSpGEMM:
             blocks, col_tile, n_tiles, ell = dev_payload
             ell_dev = dataclasses.replace(
                 ell, blocks=blocks, col_tile=col_tile, n_tiles=n_tiles)
-            return consume_one(ell_dev, ell)
+            return consume_one(ell_dev, ell, i)
 
         def consume(dev_payload, i):
             if isinstance(dev_payload, CoalescedPayload):
@@ -603,7 +604,7 @@ class AiresSpGEMM:
         feat = FeatureSpec(int(dense.shape[0]), int(dense.shape[1]), 4, 0.0)
         return self._stream(
             prepared,
-            lambda ell_dev, ell: bcsr_spmm(
+            lambda ell_dev, ell, _: bcsr_spmm(
                 ell_dev, dense_dev, interpret=cfg.interpret,
                 bricks=int(ell.n_tiles.sum())),
             int(dense.shape[1]), feat=feat)
@@ -645,6 +646,45 @@ class AiresSpGEMM:
         self.backward_stats_log.append(stats)
         return dh
 
+    def attend(self, a: CSR, z: jax.Array, s_src: jax.Array,
+               s_dst: jax.Array, heads: int,
+               negative_slope: float) -> jax.Array:
+        """GAT's aggregation, streamed: (n, heads, head_width) where, for
+        head k, row i is Σ_j softmax_j(LeakyReLU(s_dst[i, k] + s_src[j, k]))
+        z[j, k] over the nonzeros j of A's row i (`kernels/gat_attn.py`),
+        LeakyReLU with the given negative slope.
+
+        z is (n, heads * head_width), s_src and s_dst (n, heads). The pass
+        streams A's forward plan, its bricks and cache keys, as `__call__`
+        does, with the bricks as the mask; its `StreamStats` join
+        `forward_stats_log`. Forward only: no VJP is defined.
+        """
+        from repro.kernels import gat_attention
+        from repro.kernels.gat_attn import pack_sources
+
+        cfg = self.config
+        n, width = z.shape
+        fwd = self._prepare(a, (n, width), transpose=False)
+        # Packed once, for every segment's kernel.
+        zs = pack_sources(z, s_src, heads=heads, head_width=width // heads,
+                          bk=cfg.bk)
+        s_dst = jnp.pad(s_dst, ((0, cfg.bm), (0, 0)))
+        starts = [seg.row_start for seg in fwd.segs]
+
+        def consume_one(ell_dev, ell, i):
+            rows = s_dst[starts[i]:starts[i] + ell.n_row_blocks * ell.bm]
+            return gat_attention(ell_dev, zs, rows, heads=heads,
+                                 head_width=width // heads,
+                                 negative_slope=negative_slope,
+                                 interpret=cfg.interpret,
+                                 bricks=int(ell.n_tiles.sum()))
+
+        out, stats = self._stream(fwd, consume_one, width,
+                                  feat=FeatureSpec(n, width, 4, 0.0))
+        self.last_stream_stats = stats
+        self.forward_stats_log.append(stats)
+        return out
+
     def gcn_layer(self, a: CSR, h: jax.Array, w: jax.Array,
                   b: jax.Array) -> jax.Array:
         """Differentiable fused layer Y = σ((A H) W + b), Fig. 1 chain.
@@ -668,7 +708,7 @@ class AiresSpGEMM:
             h_dev = jax.device_put(h_in)
             y, stats = self._stream(
                 fwd,
-                lambda ell_dev, ell: fused_gcn_layer(
+                lambda ell_dev, ell, _: fused_gcn_layer(
                     ell_dev, h_dev, w_in, b_in, interpret=cfg.interpret),
                 int(h_in.shape[1]))
             self.last_stream_stats = stats

@@ -61,15 +61,19 @@ def _vmem_bytes(shape, dtype) -> int:
 
 
 def step_shape(rows: int, ell_w: int, bm: int, bk: int, f_pad: int,
-               a_dtype, h_dtype, out_dtype) -> tuple:
+               a_dtype, h_dtype, out_dtype, ring_width: int = 0,
+               row_bytes: int = 0) -> tuple:
     """(group, chunk): row blocks and ELL slots one grid step covers.
 
-    The pipeline double-buffers the step's bricks and output block; the
-    ring holds `RING` tiles of H. A group takes all `ell_w` slots of as
-    many row blocks as fit; where not even one row block's do, the step
-    takes one row block and as many slots as fit."""
-    room = VMEM_STEP_BYTES - RING * _vmem_bytes((bk, f_pad), h_dtype)
-    out_row = 2 * _vmem_bytes((bm, f_pad), out_dtype)
+    The pipeline double-buffers the step's bricks and output block (of
+    `f_pad` columns); the ring holds `RING` tiles of `ring_width` columns
+    (0: `f_pad`). `row_bytes` is VMEM a kernel takes per row block beside
+    those. A group takes all `ell_w` slots of as many row blocks as fit;
+    where not even one row block's do, the step takes one row block and as
+    many slots as fit."""
+    room = VMEM_STEP_BYTES - RING * _vmem_bytes((bk, ring_width or f_pad),
+                                                h_dtype)
+    out_row = 2 * _vmem_bytes((bm, f_pad), out_dtype) + row_bytes
     brick = 2 * _vmem_bytes((bm, bk), a_dtype)
     if out_row + ell_w * brick <= room:
         return max(1, min(rows, room // (out_row + ell_w * brick))), ell_w
@@ -77,11 +81,12 @@ def step_shape(rows: int, ell_w: int, bm: int, bk: int, f_pad: int,
 
 
 def grid_steps(n_rb: int, ell_w: int, bm: int, bk: int, f_pad: int,
-               a_dtype, h_dtype, out_dtype=jnp.float32) -> int:
-    """Grid steps `bcsr_spmm_pallas` runs for a segment of these shapes."""
+               a_dtype, h_dtype, out_dtype=jnp.float32, ring_width: int = 0,
+               row_bytes: int = 0) -> int:
+    """Grid steps a kernel of `step_shape`'s shapes runs for a segment."""
     rows = smem_rows_per_call(n_rb, ell_w)
     group, chunk = step_shape(rows, ell_w, bm, bk, f_pad, a_dtype, h_dtype,
-                              out_dtype)
+                              out_dtype, ring_width, row_bytes)
     return -(-n_rb // rows) * -(-rows // group) * -(-ell_w // chunk)
 
 
@@ -94,9 +99,16 @@ def _brick_product(acc, a, h):
     return acc
 
 
-def _spmm_kernel(n_tiles_ref, col_ref, a_ref, h_ref, o_ref, ring, sems,
-                 cols, *, rows: int, group: int, chunk: int, ell_w: int,
-                 bm: int, bk: int):
+def slot_walk(n_tiles_ref, col_ref, src_ref, ring, sems, cols, *,
+              rows: int, group: int, chunk: int, ell_w: int, bk: int):
+    """The walk over a grid step's populated slots that both Block-ELL
+    kernels share: lists the column tiles of the slots walked into the
+    SMEM scratch `cols`, starts the first copies of `src_ref`'s
+    (bk, width) tiles into the VMEM ring, and returns
+    (first row block, row blocks in the step, first slot of the chunk,
+    end(r), step(k)). Walked brick k's tile is `ring[k % RING]` once
+    `step(k)`, which also starts the copy RING - 1 bricks ahead, returns.
+    """
     g, c = pl.program_id(0), pl.program_id(1)
     first = g * group                       # the group's first row block
     n_rows = jnp.minimum(group, rows - first)   # fewer in the tail group
@@ -118,7 +130,7 @@ def _spmm_kernel(n_tiles_ref, col_ref, a_ref, h_ref, o_ref, ring, sems,
 
     def copy(k):
         slot = k % depth
-        return pltpu.make_async_copy(h_ref.at[pl.ds(cols[k] * bk, bk)],
+        return pltpu.make_async_copy(src_ref.at[pl.ds(cols[k] * bk, bk)],
                                      ring.at[slot], sems.at[slot])
 
     def start(k):
@@ -129,11 +141,26 @@ def _spmm_kernel(n_tiles_ref, col_ref, a_ref, h_ref, o_ref, ring, sems,
     for k in range(depth - 1):
         start(k)
 
+    def step(k):
+        start(k + depth - 1)
+        copy(k).wait()
+
+    return first, n_rows, lo, end, step
+
+
+def _spmm_kernel(n_tiles_ref, col_ref, a_ref, h_ref, o_ref, ring, sems,
+                 cols, *, rows: int, group: int, chunk: int, ell_w: int,
+                 bm: int, bk: int):
+    c = pl.program_id(1)
+    _, n_rows, lo, end, step = slot_walk(
+        n_tiles_ref, col_ref, h_ref, ring, sems, cols, rows=rows,
+        group=group, chunk=chunk, ell_w=ell_w, bk=bk)
+    depth = ring.shape[0]
+
     def row(r, k):
         def walk(s, carry):
             acc, k = carry
-            start(k + depth - 1)
-            copy(k).wait()
+            step(k)
             acc = _brick_product(acc, a_ref[r, s - lo], ring[k % depth])
             return acc, k + 1
 
@@ -146,6 +173,47 @@ def _spmm_kernel(n_tiles_ref, col_ref, a_ref, h_ref, o_ref, ring, sems,
         return k
 
     jax.lax.fori_loop(0, n_rows, row, 0)
+
+
+def brick_spec(group: int, chunk: int, bm: int, bk: int) -> pl.BlockSpec:
+    """The BlockSpec of a step's (group, chunk) bricks."""
+    def brick_index(g, c, n_tiles_ref, col_ref):
+        if group > 1:
+            return g, c, 0, 0
+        # Chunks past the row block's last populated slot keep the block
+        # already in VMEM, so they cost no copy.
+        last = jnp.maximum(n_tiles_ref[g] - 1, 0) // chunk
+        return g, jnp.minimum(c, last), 0, 0
+
+    return pl.BlockSpec((group, chunk, bm, bk), brick_index)
+
+
+def call_in_parts(call, n_tiles, col_flat, row_args, shared, *, rows: int,
+                  ell_w: int, bm: int, width: int, out_dtype) -> jax.Array:
+    """`call(n_tiles, col_flat, *row_args, *shared)` over a segment whose
+    tile table does not fit SMEM: calls over slices of `rows` row blocks
+    (the leading axis of `n_tiles` and of each of `row_args`), each
+    writing its (rows * bm, width) rows of the output."""
+    n_rb = n_tiles.shape[0]
+    if rows == n_rb:
+        return call(n_tiles, col_flat, *row_args, *shared)
+
+    def one_call(i, out):
+        # The last call is shifted back to end at n_rb; the rows it shares
+        # with the previous call are recomputed to the same values.
+        start = jnp.minimum(i * rows, n_rb - rows)
+        part = call(
+            jax.lax.dynamic_slice_in_dim(n_tiles, start, rows),
+            jax.lax.dynamic_slice_in_dim(col_flat, start * ell_w,
+                                         rows * ell_w),
+            *[jax.lax.dynamic_slice_in_dim(x, start, rows)
+              for x in row_args], *shared)
+        return jax.lax.dynamic_update_slice_in_dim(out, part, start * bm,
+                                                   axis=0)
+
+    return jax.lax.fori_loop(
+        0, -(-n_rb // rows), one_call,
+        jnp.zeros((n_rb * bm, width), out_dtype))
 
 
 def _split_spmm(blocks, col_tile, n_tiles, h, *, bm: int, bk: int,
@@ -162,14 +230,6 @@ def _split_spmm(blocks, col_tile, n_tiles, h, *, bm: int, bk: int,
     chunk = min(chunk or auto[1], ell_w)
     col_flat = col_tile.reshape(n_rb * ell_w)
 
-    def brick_index(g, c, n_tiles_ref, col_ref):
-        if group > 1:
-            return g, c, 0, 0
-        # Chunks past the row block's last populated slot keep the block
-        # already in VMEM, so they cost no copy.
-        last = jnp.maximum(n_tiles_ref[g] - 1, 0) // chunk
-        return g, jnp.minimum(c, last), 0, 0
-
     call = pl.pallas_call(
         functools.partial(_spmm_kernel, rows=rows, group=group, chunk=chunk,
                           ell_w=ell_w, bm=bm, bk=bk),
@@ -177,7 +237,7 @@ def _split_spmm(blocks, col_tile, n_tiles, h, *, bm: int, bk: int,
             num_scalar_prefetch=2,
             grid=(pl.cdiv(rows, group), pl.cdiv(ell_w, chunk)),
             in_specs=[
-                pl.BlockSpec((group, chunk, bm, bk), brick_index),
+                brick_spec(group, chunk, bm, bk),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((group * bm, f_pad),
@@ -194,24 +254,9 @@ def _split_spmm(blocks, col_tile, n_tiles, h, *, bm: int, bk: int,
         ),
         interpret=interpret,
     )
-    if rows == n_rb:
-        return call(n_tiles, col_flat, blocks, h)
-
-    def one_call(i, out):
-        # The last call is shifted back to end at n_rb; the rows it shares
-        # with the previous call are recomputed to the same values.
-        start = jnp.minimum(i * rows, n_rb - rows)
-        part = call(
-            jax.lax.dynamic_slice_in_dim(n_tiles, start, rows),
-            jax.lax.dynamic_slice_in_dim(col_flat, start * ell_w,
-                                         rows * ell_w),
-            jax.lax.dynamic_slice_in_dim(blocks, start, rows), h)
-        return jax.lax.dynamic_update_slice_in_dim(out, part, start * bm,
-                                                   axis=0)
-
-    return jax.lax.fori_loop(
-        0, -(-n_rb // rows), one_call,
-        jnp.zeros((n_rb * bm, f_pad), out_dtype))
+    return call_in_parts(call, n_tiles, col_flat, (blocks,), (h,),
+                         rows=rows, ell_w=ell_w, bm=bm, width=f_pad,
+                         out_dtype=out_dtype)
 
 
 @functools.partial(

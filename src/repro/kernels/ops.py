@@ -15,6 +15,7 @@ import numpy as np
 from repro.kernels import bcsr_spmm as _bcsr
 from repro.kernels import decode_attn as _dec
 from repro.kernels import flash_attn as _flash
+from repro.kernels import gat_attn as _gat
 from repro.sparse.formats import BlockELL
 from repro.trace import span
 
@@ -88,6 +89,51 @@ def bcsr_spmm(
             out_dtype=out_dtype,
         )
         return out[: ell.n_rows, :f]
+
+
+def gat_attention(
+    ell: BlockELL,
+    zs: jax.Array,
+    s_dst: jax.Array,
+    *,
+    heads: int,
+    head_width: int,
+    negative_slope: float,
+    bricks: int,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """GAT's attention over one segment: (ell.n_rows, heads, head_width).
+
+    The segment's bricks are the mask, `zs` the pass's packed sources
+    (`gat_attn.pack_sources`), `s_dst` the destination scores of the segment's
+    n_row_blocks * bm rows, padding rows included (they are stripped). The
+    span `aires.attn` carries `grid_steps`, `heads` and, as for
+    `aires.kernel`, the `bricks` walked, from the host copy of `n_tiles`.
+    """
+    if interpret is None:
+        interpret = _on_cpu()
+    steps = _gat.grid_steps(
+        ell.n_row_blocks, ell.ell_width, ell.bm, ell.bk, heads, head_width,
+        jax.dtypes.canonicalize_dtype(ell.blocks.dtype))
+    with span("attn", rows=ell.n_rows, grid_steps=steps, bricks=bricks,
+              heads=heads):
+        need_k = _needed_rows(ell)
+        if zs.shape[0] < need_k:
+            zs = jnp.pad(zs, ((0, need_k - zs.shape[0]), (0, 0)))
+        out = _gat.gat_attn_pallas(
+            jnp.asarray(ell.blocks),
+            jnp.asarray(ell.col_tile),
+            jnp.asarray(ell.n_tiles),
+            zs,
+            s_dst,
+            heads=heads,
+            head_width=head_width,
+            negative_slope=negative_slope,
+            bm=ell.bm,
+            bk=ell.bk,
+            interpret=interpret,
+        )
+        return out[: ell.n_rows]
 
 
 def fused_gcn_layer(

@@ -27,6 +27,29 @@ def bcsr_spmm_ref(blocks, col_tile, n_tiles, h, *, bm: int, bk: int):
                    preferred_element_type=jnp.float32)
 
 
+def gat_attn_ref(blocks, col_tile, n_tiles, z, s_src, s_dst, *, heads: int,
+                 head_width: int, bm: int, bk: int, slope: float = 0.2):
+    """Dense masked softmax attention over the densified bricks' pattern:
+    z (K_pad, heads * head_width), s_src (K_pad, heads), s_dst
+    (n_rb * bm, heads) -> (n_rb * bm, heads, head_width); a row with no
+    nonzero gives zeros."""
+    ones = jnp.where(blocks != 0, 1.0, 0.0)
+    live = bcsr_spmm_ref(ones, col_tile, n_tiles,
+                         jnp.eye(z.shape[0], dtype=jnp.float32),
+                         bm=bm, bk=bk) > 0                  # (rows, K_pad)
+    e = s_dst[:, None, :] + s_src[None, :, :]              # (rows, K_pad, h)
+    e = jnp.where(e > 0, e, slope * e)
+    e = jnp.where(live[:, :, None], e, -jnp.inf)
+    m = jnp.max(e, axis=1, keepdims=True)
+    p = jnp.where(live[:, :, None], jnp.exp(e - jnp.where(
+        jnp.isfinite(m), m, 0.0)), 0.0)
+    den = jnp.sum(p, axis=1)                                # (rows, h)
+    zh = z.reshape(z.shape[0], heads, head_width)
+    out = jnp.einsum("ijh,jhf->ihf", p, zh,
+                     precision=jax.lax.Precision.HIGHEST)
+    return out / jnp.where(den > 0, den, 1.0)[:, :, None]
+
+
 def fused_gcn_layer_ref(blocks, col_tile, n_tiles, h, w, b, *, bm: int, bk: int):
     x = bcsr_spmm_ref(blocks, col_tile, n_tiles, h, bm=bm, bk=bk)
     return jnp.maximum(x @ w.astype(jnp.float32) + b.astype(jnp.float32), 0.0)

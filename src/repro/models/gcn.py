@@ -70,6 +70,16 @@ def gcn_forward(cfg: GCNConfig, params, a, h0: jnp.ndarray,
     return h
 
 
+def serve_combine(x: jnp.ndarray, ws, layer: int) -> jnp.ndarray:
+    """A served request's combination after its aggregation x: x W_layer
+    at full float32 (not the TPU's default single bfloat16 pass), relu
+    between layers; the last layer is linear and has no bias."""
+    h = jnp.dot(x, ws[layer], precision=jax.lax.Precision.HIGHEST)
+    if layer < len(ws) - 1:
+        h = jnp.maximum(h, 0.0)
+    return h
+
+
 def gcn_loss(cfg: GCNConfig, params, a, h0, labels,
              engine: Optional[object] = None) -> jnp.ndarray:
     logits = gcn_forward(cfg, params, a, h0, engine).astype(jnp.float32)

@@ -22,7 +22,10 @@ Three mechanisms do the work:
 
 Request semantics: a request with L weight matrices computes
     h ← relu((A h) Wₗ) for l < L-1;  output = (A h) W_{L-1}
-(final layer linear); L = 0 returns the bare aggregation A·H.
+(final layer linear); L = 0 returns the bare aggregation A·H. A request
+whose `model` is a `GATConfig` runs that GAT instead (`models/gat.py`):
+per layer a projection, the streamed attention over A's bricks
+(`AiresSpGEMM.attend`) and the heads' combination.
 """
 from __future__ import annotations
 
@@ -59,6 +62,8 @@ from repro.io.tiers import (
     TierSpec,
     TPU_V5E_SYSTEM,
 )
+from repro.models import gat, gcn
+from repro.models.gat import GATConfig
 from repro.sparse.formats import CSR, BlockELL
 from repro.sparse.partition import Partition, partition_graph
 from repro.sparse.updates import EdgeDelta, apply_edge_updates
@@ -148,7 +153,7 @@ class EngineConfig:
 
 @dataclasses.dataclass
 class InferenceRequest:
-    """One GCN inference against a registered graph.
+    """One GCN (or GAT) inference against a registered graph.
 
     `deadline_s` is a relative deadline: the request must *finish* within
     that many wall seconds of submit(). Submission rejects requests whose
@@ -163,6 +168,19 @@ class InferenceRequest:
     deadline_s: Optional[float] = None
     submitted_s: float = -1.0             # monotonic stamp set by submit()
     estimated_cost_s: float = 0.0         # modeled cost set by submit()
+    # The model the request runs: None for the GCN above; a GATConfig for a
+    # GAT, whose `weights` are its per-layer {"w", "a_src", "a_dst"}.
+    model: Optional[GATConfig] = None
+
+    def stream_widths(self) -> List[int]:
+        """The width of each streamed pass the request needs alone."""
+        if self.model is not None:
+            return [self.model.stream_width(layer)
+                    for layer in range(len(self.weights))]
+        widths = [int(self.features.shape[1])]
+        for w in list(self.weights)[:-1]:
+            widths.append(int(np.asarray(w).shape[1]))
+        return widths
 
 
 @dataclasses.dataclass
@@ -681,11 +699,8 @@ class ServingEngine:
         """Modeled seconds to serve `request`: one streamed pass per layer,
         each at that layer's activation width. `spec` pins the pricing
         spec (unmemoized); default is the calibrated `cost_spec()`."""
-        widths = [int(request.features.shape[1])]
-        for w in list(request.weights)[:-1]:
-            widths.append(int(w.shape[1]))
         return sum(self._pass_cost(request.graph, wd, spec=spec)
-                   for wd in widths)
+                   for wd in request.stream_widths())
 
     def estimate_group_cost(self, name: str, group: Sequence[InferenceRequest]
                             ) -> float:
@@ -695,14 +710,16 @@ class ServingEngine:
         `max_batch_features`, each pass priced by the memoized
         `PipelinePlan.estimate()` cost at its concatenated width. This is
         the per-group cost the continuous loop's queue-position EDF
-        accumulates into time-to-front."""
+        accumulates into time-to-front. A GAT request streams alone."""
         cap = self.config.max_batch_features
         per_req: List[List[int]] = []
-        for r in group:
-            ws = list(r.weights)
-            per_req.append([int(r.features.shape[1])]
-                           + [int(np.asarray(w).shape[1]) for w in ws[:-1]])
         total = 0.0
+        for r in group:
+            if r.model is not None:
+                total += sum(self._pass_cost(name, w)
+                             for w in r.stream_widths())
+            else:
+                per_req.append(r.stream_widths())
         for layer in range(max((len(lv) for lv in per_req), default=0)):
             width = 0
             for lv in per_req:
@@ -1016,40 +1033,63 @@ class ServingEngine:
         with span("engine.inputs"):
             acts = [jnp.asarray(np.asarray(r.features, dtype=np.float32))
                     for r in group]
-            wss = [[jnp.asarray(np.asarray(w, dtype=np.float32))
+            wss = [[jax.tree_util.tree_map(
+                        lambda x: jnp.asarray(np.asarray(x, np.float32)), w)
                     for w in r.weights] for r in group]
-        n_aggs = [max(len(ws), 1) for ws in wss]
         outputs: Dict[int, np.ndarray] = {}
         done_s: Dict[int, tuple] = {}
-        for layer in range(max(n_aggs)):
-            live = [i for i in range(len(group)) if layer < n_aggs[i]]
+
+        def finish(i: int) -> None:
+            with span("engine.readback", request=group[i].request_id):
+                outputs[i] = np.asarray(acts[i])
+            now = time.perf_counter()
+            done_s[group[i].request_id] = (now - t0, now - g0)
+
+        # GCN requests go layer by layer, their aggregations merged into
+        # column-concat passes.
+        gcns = [i for i, r in enumerate(group) if r.model is None]
+        n_aggs = {i: max(len(wss[i]), 1) for i in gcns}
+        for layer in range(max(n_aggs.values(), default=0)):
+            live = [i for i in gcns if layer < n_aggs[i]]
             aggregated = self._batched_aggregate(
                 eng, a, [acts[i] for i in live])
             for i, x in zip(live, aggregated):
                 ws = wss[i]
-                rid = group[i].request_id
                 if layer < len(ws):
-                    # Requests are float32: combine at full float32, not
-                    # the TPU's default single bfloat16 pass.
-                    with span("engine.combine", request=rid):
-                        h = jnp.dot(x, ws[layer],
-                                    precision=jax.lax.Precision.HIGHEST)
-                        if layer < len(ws) - 1:
-                            h = jnp.maximum(h, 0.0)   # relu between layers
+                    with span("engine.combine",
+                              request=group[i].request_id):
+                        acts[i] = gcn.serve_combine(x, ws, layer)
                 else:                             # bare aggregation request
-                    h = x
-                acts[i] = h
+                    acts[i] = x
                 if layer == n_aggs[i] - 1:
-                    with span("engine.readback", request=rid):
-                        outputs[i] = np.asarray(h)
-                    now = time.perf_counter()
-                    done_s[rid] = (now - t0, now - g0)
+                    finish(i)
+        # A GAT request streams alone, so it runs its layers in turn:
+        # only one request's activations are held at a time.
+        for i, r in enumerate(group):
+            if r.model is None:
+                continue
+            for layer in range(len(wss[i])):
+                acts[i] = self._gat_layer(eng, a, r, acts[i], wss[i], layer)
+            finish(i)
         results = [InferenceResult(group[i].request_id, name, outputs[i])
                    for i in range(len(group))]
         stats = GroupStats()
         for s in eng.forward_stats_log[mark:]:
             stats.accumulate(s)
         return results, done_s, stats
+
+    @staticmethod
+    def _gat_layer(eng: AiresSpGEMM, a: CSR, request: InferenceRequest, h,
+                   params: list, layer: int):
+        """One GAT layer of `request`: project and score, the streamed
+        attention, then the heads' combination, skip and ELU."""
+        cfg, rid = request.model, request.request_id
+        with span("engine.project", request=rid):
+            z, s_src, s_dst = gat.project(cfg, layer, params[layer], h)
+        x = eng.attend(a, z, s_src, s_dst, cfg.heads[layer],
+                       cfg.negative_slope)
+        with span("engine.combine", request=rid):
+            return gat.combine(cfg, layer, x, h)
 
     def _batched_aggregate(self, eng: AiresSpGEMM, a: CSR,
                            hs: List[jnp.ndarray]) -> List[jnp.ndarray]:

@@ -19,9 +19,14 @@ nest, the second named is the inner:
   aires.kernel            host dispatch of one segment's Pallas kernel,
                           with its grid_steps and the bricks it walks
   aires.kernel.sync       its read of the brick's largest column tile
+  aires.attn              host dispatch of one segment's GAT attention
+                          kernel, with its grid_steps, bricks and heads
+                          (its column tile read is aires.kernel.sync too)
   aires.engine.group      `ServingEngine.serve_group`
   aires.engine.inputs     the requests' features and weights to device
-  aires.engine.combine    one request's combination matmul and relu
+  aires.engine.project    one GAT request's projection and scores
+  aires.engine.combine    one request's combination matmul and relu, or a
+                          GAT layer's heads, skip and ELU
   aires.engine.readback   one request's output copied to the host
   aires.train.step        `make_gcn_train_step`'s step
   aires.train.update      its optimizer update
@@ -38,8 +43,9 @@ PREFIX = "aires."
 SPANS = tuple(PREFIX + n for n in (
     "pass", "pass.wait", "assemble", "upload",
     "cache.probe", "cache.promote", "cache.store", "cache.demote",
-    "kernel", "kernel.sync",
-    "engine.group", "engine.inputs", "engine.combine", "engine.readback",
+    "kernel", "kernel.sync", "attn",
+    "engine.group", "engine.inputs", "engine.project", "engine.combine",
+    "engine.readback",
     "train.step", "train.update",
     "prep", "prep.robw", "prep.densify",
 ))

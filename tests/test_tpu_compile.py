@@ -1,4 +1,4 @@
-"""Compile the Block-ELL kernel for a described TPU v5e, with no chip.
+"""Compile the Block-ELL kernels for a described TPU v5e, with no chip.
 
 The TPU compiler is installed beside JAX and compiles for a topology that
 is described rather than attached, so these tests catch what interpret
@@ -11,6 +11,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bcsr_spmm import bcsr_spmm_pallas
+from repro.kernels.gat_attn import gat_attn_pallas, pack_sources, padded_width
+from repro.models.gat import scores
 
 V5E_HBM_BYTES = 16 * 10**9
 F = 256   # gcn_paper feature width
@@ -91,3 +94,60 @@ def test_bcsr_spmm_compiles_for_v5e(name, one_chip, no_persistent_cache):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES // 4, used
+
+
+# (n_rb, ell_w, heads, head width): the largest segment of the rUSA.gat_serve
+# plan (478,800 rows at plan width 1,024: 11,991 row blocks, ELL 64), at
+# gat_ppi's layers 1-2 (4 x 256) and layer 3 (6 x 121, padded per head to
+# 128); and a row block too wide for VMEM, whose slots come in chunks.
+GAT_SEGMENTS = {
+    "rusa-4x256": (11991, 64, 4, 256),
+    "rusa-6x121": (11991, 64, 6, 121),
+    "wide-64x4096": (64, 4096, 4, 256),
+}
+GAT_ROWS = 478800
+
+
+@pytest.mark.parametrize("name", list(GAT_SEGMENTS))
+def test_gat_attn_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    n_rb, ell_w, heads, hw = GAT_SEGMENTS[name]
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = gat_attn_pallas.lower(
+        spec((n_rb, ell_w, 8, 8), jnp.float32),
+        spec((n_rb, ell_w), jnp.int32),
+        spec((n_rb,), jnp.int32),
+        spec((GAT_ROWS, heads * padded_width(hw) + 128), jnp.float32),
+        spec((n_rb * 8, heads), jnp.float32),
+        heads=heads, head_width=hw, negative_slope=0.2, bm=8, bk=8)
+    assert "tpu_custom_call" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < V5E_HBM_BYTES // 2, used
+
+
+# Per head: (heads, head width). Splitting z's 726 lanes into (6, 121), or
+# transposing the scores within a tile, took the v5e compiler 85-108 s at
+# 478,800 rows; the column slices and lane gather that replace them, about
+# 2 s. A request compiles these once per layer shape, in its set-up.
+QUICK_COMPILE_S = 30
+
+
+@pytest.mark.parametrize("heads,hw", [(4, 256), (6, 121)])
+def test_gat_sources_and_scores_compile_quickly(heads, hw, one_chip,
+                                                no_persistent_cache):
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    for fn, args, kw in (
+            (pack_sources, (spec((GAT_ROWS, heads * hw)),
+                            spec((GAT_ROWS, heads))),
+             dict(heads=heads, head_width=hw, bk=8)),
+            (jax.jit(scores), (spec((GAT_ROWS, heads * hw)),
+                               spec((heads, hw))), {})):
+        t = time.perf_counter()
+        fn.lower(*args, **kw).compile()
+        assert time.perf_counter() - t < QUICK_COMPILE_S, fn

@@ -4,9 +4,11 @@ A streamed pass traced by `jax.profiler` on the CPU: the `aires.*` spans
 nest as the layers do (the `col_tile` sync inside the kernel dispatch
 inside the pass; a demotion inside the cache store that caused it), every
 name emitted is in `SPANS` and every name in `SPANS` is emitted by a
-serving batch and a training step. `demoted_bytes` carries the cache's
+serving batch with a GAT request and a training step; without the GAT
+request, every name but its own. `demoted_bytes` carries the cache's
 device->host copies from `StreamStats` up to `BatchReport`.
 """
+import dataclasses
 import glob
 import os
 
@@ -73,6 +75,17 @@ def _traced(directory, fn):
     return events, threads
 
 
+def _stats(directory, name):
+    """The stats of each host event `name` in the trace under directory."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
+                      recursive=True)
+    return [dict(e.stats) for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events if e.name == name]
+
+
 def _inside(events, inner, *outer, every=True) -> bool:
     """Some `inner` span was emitted, and each (or, with `every` False,
     one) lies within a span named in `outer`."""
@@ -110,24 +123,40 @@ def test_streamed_pass_spans_nest(tmp_path, graph):
     assert sum(n == "aires.pass" for _, _, n in events) == 2
 
 
-def test_every_span_is_listed_and_emitted(tmp_path, graph):
-    """A two-request serving batch whose tier holds one brick, then a GCN
-    training step, emit exactly the names in `SPANS`."""
+GAT_SPANS = {"aires.attn", "aires.engine.project"}
+
+
+def _gat_request(rng):
+    from repro.configs.gat_ppi import SMOKE
+    from repro.models.gat import gat_init
+    from repro.runtime import InferenceRequest
+
+    # SMOKE's layout, each layer streaming at most F columns.
+    cfg = dataclasses.replace(SMOKE, feature_dim=F, head_dims=(8, 8, 5))
+    params = [{k: np.asarray(v) for k, v in p.items()}
+              for p in gat_init(cfg, jax.random.PRNGKey(3))]
+    return InferenceRequest("g", rng.standard_normal((N, F)).astype(
+        np.float32), params, model=cfg)
+
+
+def _serving_and_step(a, rng, gat: bool):
+    """A two-request serving batch (one of them a GAT where `gat`) whose
+    tier holds one brick, then a GCN training step."""
     from repro.models.gcn import GCNConfig, gcn_init
     from repro.runtime import EngineConfig, InferenceRequest, ServingEngine
     from repro.train.loop import make_gcn_train_step
 
-    a, _ = graph
-    rng = np.random.default_rng(1)
     eng = _engine(a)
     serving = ServingEngine(EngineConfig(
         device_budget_bytes=_budget(a), max_batch_features=F,
         cache_device_bytes=eng.segment_cache.device_budget_bytes))
     serving.register_graph("g", a)
-    for _ in range(2):
-        serving.submit(InferenceRequest(
-            "g", rng.standard_normal((N, F)).astype(np.float32),
-            [rng.standard_normal((F, F)).astype(np.float32)] * 2))
+    serving.submit(InferenceRequest(
+        "g", rng.standard_normal((N, F)).astype(np.float32),
+        [rng.standard_normal((F, F)).astype(np.float32)] * 2))
+    serving.submit(_gat_request(rng) if gat else InferenceRequest(
+        "g", rng.standard_normal((N, F)).astype(np.float32),
+        [rng.standard_normal((F, F)).astype(np.float32)] * 2))
     cfg = GCNConfig(feature_dim=F, hidden_dims=(F,), n_classes=4,
                     out_of_core=True)
     params = gcn_init(cfg, jax.random.PRNGKey(0))
@@ -142,15 +171,42 @@ def test_every_span_is_listed_and_emitted(tmp_path, graph):
         assert len(report.results) == 2
         return step(params, init_opt(params))[0]
 
-    events, _ = _traced(tmp_path, run)
+    return run
+
+
+def test_every_span_is_listed_and_emitted(tmp_path, graph):
+    """A two-request serving batch, one GCN and one GAT request, whose
+    tier holds one brick, then a GCN training step, emit exactly the names
+    in `SPANS`; the GAT request's `aires.attn` carries its grid steps,
+    bricks and heads."""
+    a, _ = graph
+    events, _ = _traced(tmp_path, _serving_and_step(
+        a, np.random.default_rng(1), gat=True))
     assert {n for _, _, n in events} == set(SPANS)
     assert _inside(events, "aires.train.update", "aires.train.step")
     assert _inside(events, "aires.pass", "aires.train.step", every=False)
-    for name in ("aires.engine.inputs", "aires.engine.combine",
-                 "aires.engine.readback"):
+    for name in ("aires.engine.inputs", "aires.engine.project",
+                 "aires.engine.combine", "aires.engine.readback"):
         assert _inside(events, name, "aires.engine.group"), name
+    assert _inside(events, "aires.attn", "aires.pass")
+    assert _inside(events, "aires.kernel.sync", "aires.attn", "aires.kernel")
     assert _inside(events, "aires.prep.robw", "aires.prep")
     assert _inside(events, "aires.prep.densify", "aires.prep")
+    attn = _stats(tmp_path, "aires.attn")
+    # Two segments a pass, three GAT layers.
+    assert len(attn) == 6
+    for st in attn:
+        assert st["grid_steps"] >= 1 and st["bricks"] >= 1
+    assert sorted({st["heads"] for st in attn}) == [2, 3]
+
+
+def test_gcn_request_opens_the_spans_it_did(tmp_path, graph):
+    """With no GAT request, the same batch and step emit every name but
+    the GAT's own."""
+    a, _ = graph
+    events, _ = _traced(tmp_path, _serving_and_step(
+        a, np.random.default_rng(1), gat=False))
+    assert {n for _, _, n in events} == set(SPANS) - GAT_SPANS
 
 
 def test_demoted_bytes_follow_the_cache(graph):
