@@ -90,7 +90,8 @@ from repro.core.scheduler import (
     UCGScheduler,
 )
 from repro.core.spgemm import (
-    AiresConfig, AiresSpGEMM, EpochMetrics, UpdateStats, gcn_epoch,
+    AiresConfig, AiresSpGEMM, BrickPayload, EpochMetrics, UpdateStats,
+    gcn_epoch,
 )
 
 __all__ = [
@@ -115,5 +116,6 @@ __all__ = [
     "CoalescedPayload", "EDFOrderingPass", "PassContext", "PassPipeline",
     "PassReport", "PlanPass", "ShardPlacementPass", "TransferCoalescingPass",
     "deadline_order", "edf_sort",
-    "AiresConfig", "AiresSpGEMM", "EpochMetrics", "UpdateStats", "gcn_epoch",
+    "AiresConfig", "AiresSpGEMM", "BrickPayload", "EpochMetrics",
+    "UpdateStats", "gcn_epoch",
 ]

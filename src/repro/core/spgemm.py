@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Literal, Optional, Union
+from typing import Any, Callable, Dict, List, Literal, NamedTuple, Optional, Union
 
 import numpy as np
 import jax
@@ -52,6 +52,7 @@ from repro.io.streamer import StreamStats
 from repro.io.tiers import MemoryTier, Path, TierSpec, TPU_V5E_SYSTEM
 from repro.sparse.formats import (
     CSR,
+    BlockELL,
     csr_fingerprint,
     graph_cache_prefix,
     segment_fingerprint,
@@ -85,6 +86,32 @@ class AiresConfig:
     # `ell_bucket_capacity` and the autotuner, repro.core.autotune).
     # None (default) keeps the power-of-two buckets bit-exactly.
     ell_buckets: Optional[List[int]] = None
+
+
+class BrickPayload(NamedTuple):
+    """One segment's bricks as streamed: the three arrays and the host
+    `BlockELL` they were uploaded from.
+
+    Bricks are read-only, so a cached brick's host form is its `ell`'s own
+    arrays (`host_form`): the segment cache demotes it by dropping the
+    device arrays, with no device->host copy. `jax.tree_util` keeps the
+    type, so a promoted host form is again a `BrickPayload`.
+    """
+
+    blocks: Any
+    col_tile: Any
+    n_tiles: Any
+    ell: BlockELL
+
+    def host_form(self) -> "BrickPayload":
+        """The same payload with the `ell`'s numpy arrays as leaves: the
+        bytes and dtypes a device->host copy of the leaves would give
+        (`np.asarray` returns the `ell`'s array itself where the dtypes
+        agree, as the float32 / int32 bricks do)."""
+        e = self.ell
+        return BrickPayload(np.asarray(e.blocks, self.blocks.dtype),
+                            np.asarray(e.col_tile, self.col_tile.dtype),
+                            np.asarray(e.n_tiles, self.n_tiles.dtype), e)
 
 
 @dataclasses.dataclass
@@ -447,10 +474,10 @@ class AiresSpGEMM:
     # ---- pipeline-plan building + streaming executors --------------------
 
     @staticmethod
-    def device_payload(ell):
+    def device_payload(ell) -> BrickPayload:
         """Upload one BlockELL brick — the device-resident payload format
         shared by the streamer, the segment cache, and engine warm-start."""
-        return (
+        return BrickPayload(
             jax.device_put(jnp.asarray(ell.blocks)),
             jax.device_put(jnp.asarray(ell.col_tile)),
             jax.device_put(jnp.asarray(ell.n_tiles)),
@@ -583,6 +610,8 @@ class AiresSpGEMM:
             stats.directory_hit_bytes = (
                 after.directory_hit_bytes - before.directory_hit_bytes)
             stats.demoted_bytes = after.demoted_bytes - before.demoted_bytes
+            stats.demote_copied_bytes = (
+                after.demote_copied_bytes - before.demote_copied_bytes)
         # Flatten coalesced-group results back into per-segment plan order.
         flat = []
         for p in parts:

@@ -4,7 +4,9 @@ AIRES's Phase III keeps the output C on device for layer chaining, but the
 execute path still re-streamed every BlockELL segment each layer and each
 epoch. This cache closes that gap: uploaded device payloads are retained
 under a device byte budget; LRU eviction *demotes* bricks device→host
-instead of discarding them, and a later hit *promotes* them back. Both moves
+instead of discarding them, and a later hit *promotes* them back. A value
+that keeps the host arrays it was uploaded from (`host_form()`) demotes to
+them without a copy; other device arrays are copied down. Both moves
 are charged through a `TieredMemorySystem` (DMA path, tagged
 ``cache/demote`` / ``cache/promote``) so the simulate-mode `bytes_by_path`
 stays honest: a device-tier hit is free wire traffic, a host-tier hit pays
@@ -68,7 +70,9 @@ class CacheStats:
     misses: int = 0
     hit_bytes: int = 0       # wire bytes served from either tier
     miss_bytes: int = 0      # wire bytes the caller had to upload
-    demoted_bytes: int = 0   # device->host spills
+    demoted_bytes: int = 0   # device->host spills (bytes moved down a tier)
+    demote_copied_bytes: int = 0  # of those, bytes really copied
+    #                               device->host (no host form kept)
     promoted_bytes: int = 0  # host->device refills
     evicted_bytes: int = 0   # dropped from the host tier entirely
     # Sharded device tier (io/shard_cache.py): hits whose brick lives on a
@@ -104,14 +108,34 @@ class _Entry:
     nbytes: int
 
 
+def _has_host_form(node: Any) -> bool:
+    return hasattr(node, "host_form")
+
+
 def demote_to_host(value: Any):
-    """Default demotion: device arrays → host numpy (bit-identical copy)."""
+    """Default demotion: a node with `host_form()` (a read-only device
+    payload that keeps the host arrays it was uploaded from) takes that
+    form, with no transfer; any other device array is copied to host numpy
+    (bit-identical)."""
     import jax
     import numpy as np
 
-    return jax.tree_util.tree_map(
-        lambda leaf: np.asarray(leaf) if isinstance(leaf, jax.Array) else leaf,
-        value)
+    def down(node):
+        if _has_host_form(node):
+            return node.host_form()
+        return np.asarray(node) if isinstance(node, jax.Array) else node
+
+    return jax.tree_util.tree_map(down, value, is_leaf=_has_host_form)
+
+
+def demote_copy_nbytes(value: Any) -> int:
+    """Bytes of `value`'s device arrays outside any `host_form()` node:
+    what a demotion has to copy device->host."""
+    import jax
+
+    return sum(int(leaf.nbytes) for leaf in
+               jax.tree_util.tree_leaves(value, is_leaf=_has_host_form)
+               if isinstance(leaf, jax.Array))
 
 
 def promote_to_device(value: Any):
@@ -236,9 +260,10 @@ class TieredSegmentCache:
 
     * device tier — entries live in upload form (e.g. jax device buffers);
       `device_budget_bytes` is a hard cap, eviction demotes LRU-first.
-    * host tier — demoted entries (converted by `demote`, default: numpy
-      copies); `host_budget_bytes` caps it (None = unbounded); overflow is
-      dropped for good and counted in `stats.evicted_bytes`.
+    * host tier — demoted entries (converted by `demote`, default: the
+      value's own host form, else numpy copies); `host_budget_bytes` caps
+      it (None = unbounded); overflow is dropped for good and counted in
+      `stats.evicted_bytes`.
 
     `tms` (constructor or per-call) receives the DMA transfer for every
     demotion/promotion; `get_with_cost` additionally returns the modeled
@@ -368,8 +393,9 @@ class TieredSegmentCache:
         """Snapshot every live entry as (key, host-form value, wire bytes).
 
         Device-tier entries are demoted to host form (bit-identical numpy
-        copies) *without* being evicted — this is the read path for brick
-        checkpointing (`ServingEngine.checkpoint_cache`), so a serving
+        arrays, copied only where the value keeps no host form) *without*
+        being evicted — this is the read path for brick checkpointing
+        (`ServingEngine.checkpoint_cache`), so a serving
         process can persist its warm cache and a successor can
         `warm_start()` from it.
         """
@@ -552,8 +578,10 @@ class TieredSegmentCache:
             return
         self._charge(tms, MemoryTier.DEVICE, MemoryTier.HOST,
                      entry.nbytes, "cache/demote")
+        copied = demote_copy_nbytes(entry.value)
         self.stats.demoted_bytes += entry.nbytes
-        with span("cache.demote", bytes=entry.nbytes):
+        self.stats.demote_copied_bytes += copied
+        with span("cache.demote", bytes=entry.nbytes, copied=copied):
             entry = _Entry(self._demote(entry.value), entry.nbytes)
         if self.host_budget_bytes is not None:
             while self._host_used + entry.nbytes > self.host_budget_bytes:

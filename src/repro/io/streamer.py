@@ -33,8 +33,11 @@ class StreamStats:
     #                                shard placements) during this stream
     directory_hit_bytes: int = 0   # wire bytes served from a peer worker's
     #                                host copy via the CacheDirectory
-    demoted_bytes: int = 0         # bytes the cache copied device->host
-    #                                (demotions) during this stream
+    demoted_bytes: int = 0         # bytes the cache moved down from its
+    #                                device tier (demotions) during this
+    #                                stream
+    demote_copied_bytes: int = 0   # of those, bytes really copied
+    #                                device->host (values with no host form)
 
 
 class DoubleBufferedStreamer:

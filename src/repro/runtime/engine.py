@@ -45,7 +45,7 @@ from repro.checkpoint.checkpointer import (
 from repro.core.autotune import TunedSchedule, autotune_schedule
 from repro.core.calibration import CostCalibrator
 from repro.core.passes import PassPipeline, PlanPass
-from repro.core.spgemm import AiresConfig, AiresSpGEMM
+from repro.core.spgemm import AiresConfig, AiresSpGEMM, BrickPayload
 from repro.io.segment_cache import (
     CacheDirectory,
     CacheStats,
@@ -293,6 +293,7 @@ class GroupStats:
     ici_bytes: int = 0
     directory_hit_bytes: int = 0
     demoted_bytes: int = 0
+    demote_copied_bytes: int = 0
     segments_streamed: int = 0
     aggregation_passes: int = 0
 
@@ -304,6 +305,7 @@ class GroupStats:
         self.ici_bytes += stats.ici_bytes
         self.directory_hit_bytes += stats.directory_hit_bytes
         self.demoted_bytes += stats.demoted_bytes
+        self.demote_copied_bytes += stats.demote_copied_bytes
         self.segments_streamed += stats.segments
         self.aggregation_passes += 1
 
@@ -333,9 +335,11 @@ class BatchReport:
     # holds the brick. 0 with no directory attached.
     directory_hit_bytes: int = 0
     duplicate_avoided_bytes: int = 0
-    # Bytes the segment cache copied device->host (demotions) while this
-    # batch streamed.
+    # Bytes the segment cache moved down from its device tier (demotions)
+    # while this batch streamed, and of those the bytes really copied
+    # device->host: a brick that keeps its host arrays demotes uncopied.
     demoted_bytes: int = 0
+    demote_copied_bytes: int = 0
     # Admission control: requests rejected at submit() since the previous
     # report, and queued requests whose deadline expired before this batch
     # ran them.
@@ -579,18 +583,17 @@ class ServingEngine:
     def checkpoint_cache(self, directory: str, step: int = 0) -> str:
         """Persist the segment cache's bricks (both tiers) for warm_start.
 
-        Only engine-format entries — the `(blocks, col_tile, n_tiles, ell)`
-        device payload `AiresSpGEMM` streams — are checkpointed; anything
-        else sharing the cache is skipped.
+        Only engine-format entries — the `BrickPayload` `AiresSpGEMM`
+        streams — are checkpointed; anything else sharing the cache is
+        skipped.
         """
         if self.cache is None:
             raise ValueError("cache_enabled=False: nothing to checkpoint")
         bricks = []
         for key, value, nbytes in self.cache.export_entries():
-            if not (isinstance(value, tuple) and len(value) == 4
-                    and isinstance(value[3], BlockELL)):
+            if not isinstance(value, BrickPayload):
                 continue
-            ell = value[3]
+            ell = value.ell
             meta = {
                 "graph_id": key.graph_id,
                 "segment_id": key.segment_id,
@@ -1011,6 +1014,7 @@ class ServingEngine:
             directory_hit_bytes=totals.directory_hit_bytes,
             duplicate_avoided_bytes=dup,
             demoted_bytes=totals.demoted_bytes,
+            demote_copied_bytes=totals.demote_copied_bytes,
             rejected=rejected, expired=expired, request_latency=latency)
 
     def serve_group(self, name: str, group: List[InferenceRequest],

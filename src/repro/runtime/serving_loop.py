@@ -505,6 +505,7 @@ def replay_round(engine: ServingEngine, trace: Sequence[Arrival],
             ici_bytes=report.ici_bytes,
             directory_hit_bytes=report.directory_hit_bytes,
             demoted_bytes=report.demoted_bytes,
+            demote_copied_bytes=report.demote_copied_bytes,
             segments_streamed=report.segments_streamed,
             aggregation_passes=report.aggregation_passes))
         clock.advance_to(t)

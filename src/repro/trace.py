@@ -15,7 +15,8 @@ nest, the second named is the inner:
   aires.cache.probe       the segment cache lookup before an upload
   aires.cache.promote     a host-tier (or peer) brick put back on device
   aires.cache.store       the segment cache insert after an upload
-  aires.cache.demote      a device brick copied down to the host tier
+  aires.cache.demote      a device brick moved down to the host tier, with
+                          the bytes it copied device->host (`copied`)
   aires.kernel            host dispatch of one segment's Pallas kernel,
                           with its grid_steps and the bricks it walks
   aires.kernel.sync       its read of the brick's largest column tile

@@ -222,6 +222,42 @@ def test_epoch2_exact_under_cache_demotion_pressure(quickstart_graph):
     assert stats.host_hits > 0, "epoch 2 should be served by promotions"
 
 
+@pytest.mark.parametrize("shards", [1, 2])
+def test_half_pass_tier_demotes_bricks_without_copies(quickstart_graph,
+                                                      shards, tmp_path):
+    """A device tier of about half a pass demotes every pass's bricks, and
+    promotes them back the next, without a device->host copy: the outputs
+    are a cache-off engine's bit for bit, and so are a warm-started
+    successor's from the demoted bricks' checkpoint."""
+    rng = np.random.default_rng(31)
+    a = quickstart_graph
+    h = rng.standard_normal((a.n_rows, 32)).astype(np.float32)
+    w = [rng.standard_normal((32, 16)).astype(np.float32)]
+    ref = _engine(a, cache_enabled=False)
+    ref.register_graph("lj", a)
+    want = ref.infer("lj", h, w)
+    half = dict(cache_device_bytes=max(shards, _wire_total(a, h) // 2),
+                cache_shards=shards)
+
+    eng = _engine(a, **half)
+    eng.register_graph("lj", a)
+    for _ in range(2):
+        eng.submit(InferenceRequest("lj", h, w))
+        report = eng.run_batch()
+        np.testing.assert_array_equal(report.results[0].output, want)
+        assert report.demoted_bytes > 0
+        assert report.demote_copied_bytes == 0
+    stats = eng.cache_stats()
+    assert stats.host_hits > 0, "the second pass must promote"
+    assert stats.demoted_bytes > 0 and stats.demote_copied_bytes == 0
+    eng.checkpoint_cache(str(tmp_path))
+
+    fresh = _engine(a, **half)
+    fresh.register_graph("lj", a)
+    assert fresh.warm_start(str(tmp_path)).bricks > 0
+    np.testing.assert_array_equal(fresh.infer("lj", h, w), want)
+
+
 # ---- cache-off ablation reproduces PR-1 ----------------------------------
 
 def test_cache_off_reproduces_pr1_engine_exactly(quickstart_graph):

@@ -11,6 +11,7 @@ never skip):
   * byte accounting: hit_bytes + miss_bytes equals exactly the wire bytes
     requested through get() — the invariant the serving metrics rely on.
 """
+import dataclasses
 import importlib.util
 
 import numpy as np
@@ -199,6 +200,128 @@ def test_fingerprint_distinguishes_segment_generations():
     cache.put(fresh, "new", 1)
     assert cache.get(fresh, nbytes=1) == "new"
     assert cache.get(stale, nbytes=1) == "old"
+
+
+# ---- demotion of a brick that keeps its host arrays ----------------------
+
+def _brick(seed=0, row_blocks=2, width=3):
+    """A float32 / int32 BlockELL and its `AiresSpGEMM.device_payload`."""
+    from repro.core import AiresSpGEMM
+    from repro.sparse.formats import BlockELL
+
+    rng = np.random.default_rng(seed)
+    ell = BlockELL(
+        blocks=rng.standard_normal((row_blocks, width, 8, 8)).astype(
+            np.float32),
+        col_tile=rng.integers(-1, 9, (row_blocks, width)).astype(np.int32),
+        n_tiles=rng.integers(0, width + 1, row_blocks).astype(np.int32),
+        bm=8, bk=8, n_rows=8 * row_blocks, n_cols=72)
+    return ell, AiresSpGEMM.device_payload(ell)
+
+
+def _assert_same_leaves(got, want):
+    """Same tree, and each leaf the same bytes, dtype and shape."""
+    import jax
+
+    assert type(got) is type(want)
+    got_leaves, got_def = jax.tree_util.tree_flatten(got)
+    want_leaves, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def
+    for g, w in zip(got_leaves, want_leaves):
+        if hasattr(w, "dtype"):
+            assert np.asarray(g).dtype == np.asarray(w).dtype
+            assert np.asarray(g).shape == np.asarray(w).shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        else:
+            assert g is w
+
+
+@pytest.mark.parametrize("kind", ["brick", "array", "array_and_meta"])
+def test_demotion_copies_only_values_without_host_form(kind, monkeypatch):
+    """A `device_payload` brick moves down a tier as its `ell`'s own arrays
+    (copying nothing, again after a promotion); a plain device array is
+    still copied, and `demote_copied_bytes` and the span's `copied` count
+    it."""
+    import jax.numpy as jnp
+
+    from repro.io import segment_cache
+
+    opened = []
+    real_span = segment_cache.span
+    monkeypatch.setattr(segment_cache, "span", lambda name, **attrs: (
+        opened.append((name, attrs)), real_span(name, **attrs))[1])
+    ell, brick = _brick()
+    value = {"brick": brick,
+             "array": brick.blocks,
+             "array_and_meta": (jnp.asarray(ell.blocks), "meta")}[kind]
+    nbytes = ell.nbytes()
+    copied = 0 if kind == "brick" else ell.blocks.nbytes
+    cache = TieredSegmentCache(device_budget_bytes=nbytes)
+    cache.put(_key(0), value, nbytes)
+    for _ in range(2):
+        before = dataclasses.replace(cache.stats)
+        cache.put(_key(1), "other", nbytes)     # k0 down a tier
+        assert cache.tier_of(_key(0)) == MemoryTier.HOST
+        assert cache.stats.demoted_bytes - before.demoted_bytes == nbytes
+        assert cache.stats.demote_copied_bytes - (
+            before.demote_copied_bytes) == copied
+        assert opened[-1] == ("cache.demote",
+                              {"bytes": nbytes, "copied": copied})
+        host = cache._host[_key(0)].value
+        if kind == "brick":
+            assert host.ell is ell
+            assert host.blocks is ell.blocks
+            assert host.col_tile is ell.col_tile
+            assert host.n_tiles is ell.n_tiles
+        _assert_same_leaves(cache.get(_key(0), nbytes=nbytes), value)
+        assert cache.tier_of(_key(0)) == MemoryTier.DEVICE
+
+
+def test_brick_round_trip_is_bit_identical_and_stays_a_brick():
+    """`promote_to_device(demote_to_host(p))` is `p` bit for bit, and is a
+    `BrickPayload` again, so its next demotion copies nothing either."""
+    import jax
+
+    from repro.core import BrickPayload
+    from repro.io.segment_cache import (
+        demote_copy_nbytes, demote_to_host, promote_to_device,
+    )
+
+    ell, brick = _brick(seed=1, row_blocks=3, width=2)
+    assert demote_copy_nbytes(brick) == 0
+    host = demote_to_host(brick)
+    assert isinstance(host, BrickPayload)
+    assert not any(isinstance(x, jax.Array) for x in host)
+    back = promote_to_device(host)
+    assert isinstance(back, BrickPayload)
+    assert all(isinstance(x, jax.Array) for x in back[:3])
+    _assert_same_leaves(back, brick)
+    assert demote_copy_nbytes(back) == 0
+    assert demote_to_host(back).blocks is ell.blocks
+    assert demote_copy_nbytes((brick.blocks, "meta")) == ell.blocks.nbytes
+
+
+def test_export_entries_matches_a_device_to_host_copy():
+    """Brick checkpointing reads the same structure, bytes and dtypes as a
+    device->host copy of every leaf gives, from both tiers."""
+    import jax
+
+    ell0, brick0 = _brick(seed=2)
+    ell1, brick1 = _brick(seed=3)
+    cache = TieredSegmentCache(device_budget_bytes=ell0.nbytes())
+    cache.put(_key(0), brick0, ell0.nbytes())
+    cache.put(_key(1), brick1, ell1.nbytes())   # k0 to the host tier
+    exported = {key: (value, nbytes)
+                for key, value, nbytes in cache.export_entries()}
+    for key, brick, ell in ((_key(0), brick0, ell0),
+                            (_key(1), brick1, ell1)):
+        value, nbytes = exported[key]
+        assert nbytes == ell.nbytes()
+        _assert_same_leaves(value, jax.tree_util.tree_map(
+            lambda x: np.asarray(x) if isinstance(x, jax.Array) else x,
+            brick))
+    assert cache.tier_of(_key(1)) == MemoryTier.DEVICE, \
+        "export must not evict"
 
 
 # ---- the properties (plain functions — both drivers call these) ----------

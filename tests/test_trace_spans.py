@@ -6,7 +6,8 @@ inside the pass; a demotion inside the cache store that caused it), every
 name emitted is in `SPANS` and every name in `SPANS` is emitted by a
 serving batch with a GAT request and a training step; without the GAT
 request, every name but its own. `demoted_bytes` carries the cache's
-device->host copies from `StreamStats` up to `BatchReport`.
+demotions from `StreamStats` up to `BatchReport`, and a demoted brick
+copies nothing device->host.
 """
 import dataclasses
 import glob
@@ -241,3 +242,22 @@ def test_demoted_bytes_follow_the_cache(graph):
         total += report.demoted_bytes
         assert report.demoted_bytes > 0
     assert total == serving.cache_stats().demoted_bytes
+
+
+def test_demotions_copy_nothing(tmp_path, graph):
+    """The streamed bricks keep the host arrays they were uploaded from, so
+    every `aires.cache.demote` span says `copied` 0 of its bytes, and so
+    do `demote_copied_bytes` in the stream's stats and the cache's."""
+    a, _ = graph
+    eng = _engine(a)
+    h = jnp.asarray(np.random.default_rng(4).standard_normal((N, F)),
+                    jnp.float32)
+    _traced(tmp_path, lambda: [eng(a, h), eng(a, h)])
+    demotions = _stats(tmp_path, "aires.cache.demote")
+    assert len(demotions) == 3
+    for st in demotions:
+        assert st["bytes"] > 0 and st["copied"] == 0
+    assert sum(s.demoted_bytes for s in eng.forward_stats_log) == (
+        eng.segment_cache.stats.demoted_bytes) > 0
+    assert eng.segment_cache.stats.demote_copied_bytes == 0
+    assert all(s.demote_copied_bytes == 0 for s in eng.forward_stats_log)
