@@ -209,7 +209,7 @@ def check_mosaic(ell, n_rows: int, interpret: bool) -> None:
         spec(ell.col_tile.shape, jnp.int32),
         spec(ell.n_tiles.shape, jnp.int32),
         spec((n_rows, FEATURES), jnp.float32),
-        bm=ell.bm, bk=ell.bk, bn=128, interpret=interpret).as_text()
+        bm=ell.bm, bk=ell.bk, interpret=interpret).as_text()
     if not interpret:
         check("tpu_custom_call" in text,
               "the segment kernel did not lower to a Mosaic custom call")

@@ -40,9 +40,10 @@ lowering. Three analyses:
 Wiring: the interpreters take ``analyze=`` (None → module default,
 flipped on under tests by tests/conftest.py); `PassPipeline(strict=True)`
 analyzes after every pass and attaches findings to its `PassReport`s;
-`EngineConfig.analyze_plans` forces it per serving engine; and
-scripts/lint_plans.py runs the analyzer over every benchmark-built plan
-in CI.
+and scripts/lint_plans.py runs the analyzer over every benchmark-built
+plan in CI. The real engine stream interprets no plan, so it is checked
+through the plans `AiresSpGEMM.stream_plan` builds from the same
+segments.
 """
 from __future__ import annotations
 

@@ -282,8 +282,8 @@ def autotune_schedule(engine, a: CSR, graph: str, width: int,
         from repro.sparse.partition import partition_graph
         prep0 = engine._prepare(a, shape, transpose=False)
         default_ici = sum(
-            ell.nbytes() * segment_cache.ici_hops(shard_of(k, n_shards))
-            for ell, k in zip(prep0.ells, engine._segment_keys(prep0)))
+            e.nbytes * segment_cache.ici_hops(shard_of(e.key, n_shards))
+            for e in engine._segment_entries(prep0))
         warm_ici = default_ici
         grid = (tuple(cluster_grid) if cluster_grid is not None
                 else (n_shards, 2 * n_shards, 4 * n_shards))

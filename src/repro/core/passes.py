@@ -28,9 +28,9 @@ This module is the pass manager between plan builders and interpreters:
         key either moves strictly nearer or keeps its owner.
       - :class:`TransferCoalescingPass` — merge adjacent small same-lane,
         same-path transfers into one DMA: total bytes per path are
-        conserved, per-transfer setup latency is paid once per merged
-        group, and on the real streamer the merged group becomes a single
-        upload issue (`CoalescedPayload`).
+        conserved and per-transfer setup latency is paid once per merged
+        group. It reprices the plan only: the real streamer still uploads
+        every segment in its own issue.
       - :class:`EDFOrderingPass` — deadline-aware batch ordering for
         `ServingEngine.run_batch`, priced by the same
         `PipelinePlan.estimate()` cost admission control uses. The order
@@ -381,12 +381,9 @@ class ShardPlacementPass(PlanPass):
 
 @dataclasses.dataclass
 class CoalescedPayload:
-    """Stream payloads of a merged transfer, in original segment order.
-
-    `AiresSpGEMM` uploads all member bricks in one streamer issue and
-    consumes them back-to-back; per-segment results are flattened back
-    into plan order, so outputs are bit-identical to the unmerged stream.
-    """
+    """Stream payloads of a merged transfer, in original segment order:
+    the merged op still stands for its members' segments
+    (`PipelinePlan.stream_payloads`, the analyzer's payload rules)."""
 
     payloads: List[Any]
 
@@ -410,8 +407,7 @@ class TransferCoalescingPass(PlanPass):
         regardless, so only dep order gates there.
 
     Total bytes per path are conserved (property-tested); only the
-    per-transfer latency count — and, for payload-bearing stream plans,
-    the real streamer's issue count — drops. `CacheProbeOp`s are never
+    per-transfer latency count drops. `CacheProbeOp`s are never
     merged: each brick must stay individually addressable in the cache.
 
     ``min_bytes=None`` derives the threshold per path from the

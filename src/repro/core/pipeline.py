@@ -22,15 +22,14 @@ the two:
         `TieredMemorySystem` and computes the overlap-aware makespan from
         per-lane availability — this *is* simulate mode;
       - :class:`ExecuteInterpreter` additionally runs the plan's kernel
-        thunks (scheduler execute mode) and, for the real engine path,
-        drives a `DoubleBufferedStreamer` over the plan's stream ops
-        (:meth:`ExecuteInterpreter.stream`).
+        thunks (scheduler execute mode).
 
 Simulate-vs-execute agreement is therefore true by construction — one
 plan, two interpreters — instead of cross-checked by test scaffolding.
 `PipelinePlan.estimate()` exposes a side-effect-free cost reading (cache
 probes peek, never mutate) that the serving engine uses for admission
-control.
+control. The real engine stream (`AiresSpGEMM._stream`) interprets no
+plan: it walks the segments the engine's stream plans are built from.
 
 Makespan semantics per phase (`PhaseSpec.overlap`):
 
@@ -145,8 +144,8 @@ class AllocOp:
 class TransferOp:
     """One modeled transfer over `path`. `merge` marks partial-row merge
     traffic (feeds `ScheduleMetrics.merge_io_s`, the Fig. 3 numerator).
-    `payload` optionally carries the real host payload `(index, data)` for
-    the execute interpreter's streaming backend."""
+    `payload` optionally carries the host payload `(index, data)` the op
+    stands for (`PipelinePlan.stream_payloads`)."""
 
     path: Path
     src: MemoryTier
@@ -543,77 +542,10 @@ class CostInterpreter:
 class ExecuteInterpreter(CostInterpreter):
     """Cost interpretation + real execution.
 
-    For scheduler plans, `run()` additionally invokes kernel thunks
-    (AIRES per-segment Pallas kernels into the plan's output buffer, or a
-    baseline's single reference kernel) — the metrics side is identical to
-    `CostInterpreter` by inheritance, which is the whole point.
-
-    For the real engine path, :meth:`stream` drives the plan's stream ops
-    through a `DoubleBufferedStreamer`: `jax.device_put` uploads overlap
-    kernel dispatch via JAX async dispatch, cache probes become the
-    streamer's lookup/store hooks, and the plan's wire-byte declarations
-    feed `StreamStats` — one plan, the same keys and byte counts the cost
-    interpreter models.
+    `run()` additionally invokes kernel thunks (AIRES per-segment Pallas
+    kernels into the plan's output buffer, or a baseline's single
+    reference kernel) — the metrics side is identical to `CostInterpreter`
+    by inheritance, which is the whole point.
     """
 
     execute = True
-
-    def __init__(self, spec: Optional[TierSpec] = None,
-                 segment_cache: Any = None, peek_only: bool = False,
-                 analyze: Optional[bool] = None):
-        # `spec` is only needed by run(); stream() is pure execution.
-        super().__init__(spec, segment_cache=segment_cache,
-                         peek_only=peek_only, analyze=analyze)
-
-    def stream(self, plan: PipelinePlan,
-               upload: Callable[[Any], Any],
-               consume: Callable[[Any, int], Any],
-               depth: int = 2,
-               deadline_s: Optional[float] = None,
-               max_reissue: int = 1) -> Tuple[List[Any], Any]:
-        """Run the plan's stream ops for real; returns (results, StreamStats).
-
-        Payloads are the `(index, data)` pairs the plan builder attached to
-        its stream ops; cache keys and wire bytes come from the same ops the
-        cost interpreter charges, so the two accountings cannot drift.
-        """
-        from repro.io.streamer import DoubleBufferedStreamer
-
-        if self._analyze_enabled():
-            # run() validates before interpreting; stream() is the real
-            # engine path and deserves the same gate when analysis is on
-            # (spec may be None here — the budget rules then skip).
-            plan.validate()
-            self._analyze(plan)
-
-        payloads: List[Any] = []
-        meta: Dict[Any, Tuple[Any, int, Optional[int]]] = {}
-        probed = False
-        for bound in plan.ops:
-            op = bound.op
-            if isinstance(op, CacheProbeOp) and op.payload is not None:
-                payloads.append(op.payload)
-                meta[op.payload[0]] = (op.key, op.wire_bytes, op.place_shard)
-                probed = True
-            elif isinstance(op, TransferOp) and op.payload is not None:
-                payloads.append(op.payload)
-                meta[op.payload[0]] = (None, op.nbytes, None)
-
-        cache = self.segment_cache
-        cache_lookup = cache_store = None
-        if cache is not None and probed:
-            def cache_lookup(payload):
-                key, nbytes, _ = meta[payload[0]]
-                return cache.get(key, nbytes=nbytes)
-
-            def cache_store(payload, dev):
-                key, nbytes, place = meta[payload[0]]
-                cache.put(key, dev, nbytes, shard=place)
-
-        streamer = DoubleBufferedStreamer(
-            upload, consume, depth=depth, deadline_s=deadline_s,
-            max_reissue=max_reissue,
-            payload_nbytes=lambda payload: meta[payload[0]][1],
-            cache_lookup=cache_lookup, cache_store=cache_store)
-        results = streamer.run_all(payloads)
-        return results, streamer.stats

@@ -29,7 +29,6 @@ from repro.core.pipeline import (
     LANE_DMA,
     CacheProbeOp,
     ComputeOp,
-    ExecuteInterpreter,
     PhaseSpec,
     PipelinePlan,
     ScheduleMetrics,
@@ -48,7 +47,7 @@ from repro.core.scheduler import (
 )
 from repro.io.segment_cache import SegmentKey, TieredSegmentCache
 from repro.io.shard_cache import ShardedSegmentCache
-from repro.io.streamer import StreamStats
+from repro.io.streamer import DoubleBufferedStreamer, StreamStats
 from repro.io.tiers import MemoryTier, Path, TierSpec, TPU_V5E_SYSTEM
 from repro.sparse.formats import (
     CSR,
@@ -114,6 +113,16 @@ class BrickPayload(NamedTuple):
                             np.asarray(e.n_tiles, self.n_tiles.dtype), e)
 
 
+class _Segment(NamedTuple):
+    """One segment of a prepared direction as streamed: its index in plan
+    order, its host bricks, its cache key and its wire bytes."""
+
+    i: int
+    ell: BlockELL
+    key: SegmentKey
+    nbytes: int
+
+
 @dataclasses.dataclass
 class _Prepared:
     """Host-side artifacts of one streaming direction for one graph."""
@@ -170,7 +179,7 @@ class AiresSpGEMM:
 
     def __init__(self, config: AiresConfig,
                  segment_cache: Optional[SegmentCacheLike] = None,
-                 plan_passes=None, analyze: Optional[bool] = None,
+                 plan_passes=None,
                  partition: Optional[Partition] = None):
         self.config = config
         # Partition-aware sharding (repro.sparse.partition): when set, RoBW
@@ -185,13 +194,9 @@ class AiresSpGEMM:
         # the device_put entirely — see StreamStats.cache_hit_bytes.
         self.segment_cache = segment_cache
         # Optional repro.core.passes.PassPipeline applied to every stream
-        # plan before it is estimated or executed (build → rewrite →
-        # interpret, same seam as the schedulers). None = identity.
+        # plan before it is estimated; a real stream takes the rewritten
+        # plan's shard placements from it. None = identity.
         self.plan_passes = plan_passes
-        # Static plan analysis before every real stream (repro.core
-        # .analysis): None defers to the module default (tests flip it
-        # on); the serving engine forwards EngineConfig.analyze_plans.
-        self.analyze = analyze
         self._prepared: Dict[tuple, _Prepared] = {}
         self._transposes: Dict[tuple, CSR] = {}
         self.forward_stats_log: List[StreamStats] = []
@@ -380,16 +385,19 @@ class AiresSpGEMM:
         owners = [int(part.cluster_to_shard[c]) for c in clusters]
         cache.install_owner_map(prepared.cache_ns, owners, clusters)
 
-    # ---- incremental updates (evolving graphs) ---------------------------
-
-    def _segment_keys(self, prepared: _Prepared) -> List[SegmentKey]:
-        """Every SegmentKey one prepared plan emits (mirrors
-        `_build_stream_plan`'s key construction exactly)."""
+    def _segment_entries(self, prepared: _Prepared) -> List[_Segment]:
+        """Every segment of `prepared` in plan order: the one place a
+        stream's `SegmentKey` and wire bytes are derived, for the real
+        stream, its cost plan and the edge-update diff alike."""
         cfg = self.config
-        return [SegmentKey(prepared.cache_ns, i, cfg.wire_format,
-                           tuple(ell.blocks.shape), fingerprint=fp)
+        return [_Segment(i, ell,
+                         SegmentKey(prepared.cache_ns, i, cfg.wire_format,
+                                    tuple(ell.blocks.shape), fingerprint=fp),
+                         ell.nbytes())
                 for i, (ell, fp) in enumerate(zip(prepared.ells,
                                                   prepared.fps))]
+
+    # ---- incremental updates (evolving graphs) ---------------------------
 
     def apply_edge_update(self, old: CSR, new: CSR,
                           delta: EdgeDelta) -> UpdateStats:
@@ -445,7 +453,7 @@ class AiresSpGEMM:
                         stream_new, seg.row_start, seg.row_end))
                     stats.segments_retiled += 1
                     stats.retiled_bytes += ell.nbytes()
-            old_keys = self._segment_keys(prep)
+            old_keys = [e.key for e in self._segment_entries(prep)]
             # mem is reused: the budget (and Eq. 5 split) depends on shape
             # and width, both unchanged by an edge delta; the re-packed
             # spans were re-partitioned under the same m_a.
@@ -465,7 +473,7 @@ class AiresSpGEMM:
                 # labels: migrated rows may now live in a different
                 # cluster, and the re-tiled plan's segments need owners.
                 self._install_owner_map(part, new_prep, transpose)
-            fresh = set(self._segment_keys(new_prep))
+            fresh = {e.key for e in self._segment_entries(new_prep)}
             stats.stale_keys.extend(k for k in old_keys if k not in fresh)
             stats.plans_updated += 1
         self._transposes.pop((old_fp, old.nnz, old.shape), None)
@@ -487,13 +495,14 @@ class AiresSpGEMM:
     def _build_stream_plan(self, prepared: _Prepared,
                            feat: Optional[FeatureSpec] = None,
                            spec: Optional[TierSpec] = None) -> PipelinePlan:
-        """Phase II of one streamed pass as a `PipelinePlan`.
+        """Phase II of one streamed pass as a `PipelinePlan`: one transfer
+        (or cache probe) and one modeled kernel per `_segment_entries`
+        entry, so the plan prices the keys and bytes the real stream moves.
 
-        The same plan serves both interpreters: `ExecuteInterpreter.stream`
-        drives the attached `(i, ell)` payloads through the double-buffered
-        streamer for real, and `PipelinePlan.estimate()` reads the modeled
-        cost (cache probes peek, never mutate) — that is what the serving
-        engine's admission control prices a request with.
+        `PipelinePlan.estimate()` reads its modeled cost (cache probes
+        peek, never mutate) — what the serving engine's admission control
+        prices a request with; a real stream reads only the placements the
+        plan passes write (`_stream`).
         """
         cfg = self.config
         spec = spec if spec is not None else TPU_V5E_SYSTEM
@@ -506,16 +515,13 @@ class AiresSpGEMM:
         plan.robw = prepared.plan
         plan.segments = len(prepared.ells)
         cached = self.segment_cache is not None
-        for i, (seg, ell) in enumerate(zip(prepared.segs, prepared.ells)):
-            nbytes = ell.nbytes()
+        for seg, e in zip(prepared.segs, self._segment_entries(prepared)):
             miss = TransferOp(Path.DMA, MemoryTier.HOST, MemoryTier.DEVICE,
-                              nbytes, tag="phaseII/seg", payload=(i, ell))
+                              e.nbytes, tag="phaseII/seg",
+                              payload=(e.i, e.ell))
             if cached:
-                fp = prepared.fps[i] if i < len(prepared.fps) else ""
-                key = SegmentKey(prepared.cache_ns, i, cfg.wire_format,
-                                 tuple(ell.blocks.shape), fingerprint=fp)
-                i_io = plan.add(CacheProbeOp(key, nbytes, miss,
-                                             payload=(i, ell)),
+                i_io = plan.add(CacheProbeOp(e.key, e.nbytes, miss,
+                                             payload=(e.i, e.ell)),
                                 "stream", LANE_DMA)
             else:
                 i_io = plan.add(miss, "stream", LANE_DMA)
@@ -542,101 +548,103 @@ class AiresSpGEMM:
                 plan, spec=spec, segment_cache=self.segment_cache)
         return plan
 
+    def _placements(self, prepared: _Prepared, width: int) -> Dict[int, int]:
+        """{segment index: cache shard} for every probe the configured plan
+        passes placed: the one thing a real stream reads from a rewritten
+        plan."""
+        if self.plan_passes is None:
+            return {}
+        feat = FeatureSpec(prepared.a.shape[1], width, 4, 0.0)
+        plan, _ = self.plan_passes.apply(
+            self._build_stream_plan(prepared, feat=feat),
+            segment_cache=self.segment_cache)
+        return {op.key.segment_id: op.place_shard
+                for op in plan.phase_ops("stream")
+                if isinstance(op, CacheProbeOp)
+                and op.place_shard is not None}
+
     def _stream(self, prepared: _Prepared, consume_one: Callable,
-                width: int, feat: Optional[FeatureSpec] = None) -> tuple:
-        """Run one double-buffered pass over `prepared`'s segments via the
-        execute interpreter, under the span `aires.pass`.
+                width: int) -> jax.Array:
+        """Run one double-buffered pass over `prepared`'s segments, under
+        the span `aires.pass`, and record its `StreamStats` in the
+        direction's log and `last_*stream_stats`.
 
         consume_one(ell_dev, ell, i) -> per-segment device result, from
         the segment's device arrays, its host BlockELL and its index in
-        the plan; `width` is
-        the streamed dense operand's column count. Returns
-        (row-concatenated output, StreamStats).
+        the plan; `width` is the streamed dense operand's column count.
+        Returns the row-concatenated output.
         """
+        cfg = self.config
+        cache = self.segment_cache
         with span("pass", direction="bwd" if prepared.transpose else "fwd",
                   width=width, segments=len(prepared.ells)):
-            return self._stream_pass(prepared, consume_one, feat)
+            entries = self._segment_entries(prepared)
+            place = self._placements(prepared, width)
 
-    def _stream_pass(self, prepared: _Prepared, consume_one: Callable,
-                     feat: Optional[FeatureSpec]) -> tuple:
-        from repro.core.passes import CoalescedPayload
+            def consume(dev, i):
+                blocks, col_tile, n_tiles, ell = dev
+                ell_dev = dataclasses.replace(
+                    ell, blocks=blocks, col_tile=col_tile, n_tiles=n_tiles)
+                return consume_one(ell_dev, ell, i)
 
-        cfg = self.config
-        plan = self._build_stream_plan(prepared, feat=feat)
-        if self.plan_passes is not None:
-            plan, _ = self.plan_passes.apply(
-                plan, segment_cache=self.segment_cache)
+            cache_lookup = cache_store = None
+            if cache is not None:
+                def cache_lookup(e):
+                    return cache.get(e.key, nbytes=e.nbytes)
 
-        def upload(payload):
-            _, ell = payload
-            if isinstance(ell, CoalescedPayload):
-                # One streamer issue uploads every member brick of a
-                # coalesced transfer (the pass merged adjacent small DMAs).
-                return CoalescedPayload(
-                    [(i, self.device_payload(e)) for i, e in ell.payloads])
-            return self.device_payload(ell)
+                def cache_store(e, dev):
+                    cache.put(e.key, dev, e.nbytes, shard=place.get(e.i))
 
-        def consume_device(dev_payload, i):
-            blocks, col_tile, n_tiles, ell = dev_payload
-            ell_dev = dataclasses.replace(
-                ell, blocks=blocks, col_tile=col_tile, n_tiles=n_tiles)
-            return consume_one(ell_dev, ell, i)
+                # Copy, not alias: TieredSegmentCache.stats mutates in place.
+                before = dataclasses.replace(cache.stats)
+            streamer = DoubleBufferedStreamer(
+                lambda e: self.device_payload(e.ell), consume,
+                depth=cfg.stream_depth, deadline_s=cfg.straggler_deadline_s,
+                payload_nbytes=lambda e: e.nbytes,
+                cache_lookup=cache_lookup, cache_store=cache_store)
+            parts = streamer.run_all(entries)
+            stats = streamer.stats
+            if cache is not None:
+                # Host-tier hits re-crossed the bus via device_put
+                # promotions; surface them so uploaded_bytes=0 can't misread
+                # as zero traffic. Likewise inter-chip traffic (sharded
+                # cache) and peer-host serves (cache directory).
+                # `cache.stats` may be a recomputed aggregate
+                # (ShardedSegmentCache), so snapshot-and-diff.
+                after = cache.stats
+                stats.promoted_bytes = (
+                    after.promoted_bytes - before.promoted_bytes)
+                stats.ici_bytes = after.ici_bytes - before.ici_bytes
+                stats.directory_hit_bytes = (
+                    after.directory_hit_bytes - before.directory_hit_bytes)
+                stats.demoted_bytes = (
+                    after.demoted_bytes - before.demoted_bytes)
+                stats.demote_copied_bytes = (
+                    after.demote_copied_bytes - before.demote_copied_bytes)
+            with span("assemble"):
+                out = jnp.concatenate(
+                    [p[: s.n_rows] for p, s in zip(parts, prepared.segs)],
+                    axis=0)
+        if prepared.transpose:
+            self.last_backward_stream_stats = stats
+            self.backward_stats_log.append(stats)
+        else:
+            self.last_stream_stats = stats
+            self.forward_stats_log.append(stats)
+        return out
 
-        def consume(dev_payload, i):
-            if isinstance(dev_payload, CoalescedPayload):
-                return [consume_device(dp, j)
-                        for j, dp in dev_payload.payloads]
-            return consume_device(dev_payload, i)
-
-        cache = self.segment_cache
-        # Copy, not alias: TieredSegmentCache.stats mutates in place.
-        before = (dataclasses.replace(cache.stats)
-                  if cache is not None else None)
-        interp = ExecuteInterpreter(segment_cache=cache,
-                                    analyze=self.analyze)
-        parts, stats = interp.stream(
-            plan, upload, consume, depth=cfg.stream_depth,
-            deadline_s=cfg.straggler_deadline_s)
-        if cache is not None:
-            # Host-tier hits re-crossed the bus via device_put promotions;
-            # surface them so uploaded_bytes=0 can't misread as zero traffic.
-            # Likewise inter-chip traffic (sharded cache) and peer-host
-            # serves (cache directory). `cache.stats` may be a recomputed
-            # aggregate (ShardedSegmentCache), so snapshot-and-diff.
-            after = cache.stats
-            stats.promoted_bytes = (
-                after.promoted_bytes - before.promoted_bytes)
-            stats.ici_bytes = after.ici_bytes - before.ici_bytes
-            stats.directory_hit_bytes = (
-                after.directory_hit_bytes - before.directory_hit_bytes)
-            stats.demoted_bytes = after.demoted_bytes - before.demoted_bytes
-            stats.demote_copied_bytes = (
-                after.demote_copied_bytes - before.demote_copied_bytes)
-        # Flatten coalesced-group results back into per-segment plan order.
-        flat = []
-        for p in parts:
-            if isinstance(p, list):
-                flat.extend(p)
-            else:
-                flat.append(p)
-        with span("assemble"):
-            out = jnp.concatenate(
-                [p[: s.n_rows] for p, s in zip(flat, prepared.segs)], axis=0)
-        return out, stats
-
-    def _stream_spmm(self, prepared: _Prepared, dense) -> tuple:
+    def _stream_spmm(self, prepared: _Prepared, dense) -> jax.Array:
         """X = stream(A) @ dense — shared by forward and transposed passes."""
         from repro.kernels import bcsr_spmm
 
         cfg = self.config
         dense_dev = jax.device_put(dense)  # Phase I: resident feature matrix
-        feat = FeatureSpec(int(dense.shape[0]), int(dense.shape[1]), 4, 0.0)
         return self._stream(
             prepared,
             lambda ell_dev, ell, _: bcsr_spmm(
                 ell_dev, dense_dev, interpret=cfg.interpret,
                 bricks=int(ell.n_tiles.sum())),
-            int(dense.shape[1]), feat=feat)
+            int(dense.shape[1]))
 
     # ---- differentiable public API --------------------------------------
 
@@ -646,18 +654,12 @@ class AiresSpGEMM:
         fwd = self._prepare(a, h.shape, transpose=False)
         h_dtype = h.dtype
 
-        def run_forward(h_in):
-            x, stats = self._stream_spmm(fwd, h_in)
-            self.last_stream_stats = stats
-            self.forward_stats_log.append(stats)
-            return x
-
         @jax.custom_vjp
         def spgemm(h_in):
-            return run_forward(h_in)
+            return self._stream_spmm(fwd, h_in)
 
         def spgemm_fwd(h_in):
-            return run_forward(h_in), None
+            return self._stream_spmm(fwd, h_in), None
 
         def spgemm_bwd(_, g):
             dh = self._backward_stream(a, g)
@@ -667,13 +669,9 @@ class AiresSpGEMM:
         return spgemm(h)
 
     def _backward_stream(self, a: CSR, g) -> jax.Array:
-        """dH = Aᵀ @ g via the transposed RoBW plan, with stats recorded."""
+        """dH = Aᵀ @ g via the transposed RoBW plan."""
         g = jnp.asarray(g)
-        bwd = self._prepare(a, g.shape, transpose=True)
-        dh, stats = self._stream_spmm(bwd, g)
-        self.last_backward_stream_stats = stats
-        self.backward_stats_log.append(stats)
-        return dh
+        return self._stream_spmm(self._prepare(a, g.shape, transpose=True), g)
 
     def attend(self, a: CSR, z: jax.Array, s_src: jax.Array,
                s_dst: jax.Array, heads: int,
@@ -708,66 +706,7 @@ class AiresSpGEMM:
                                  interpret=cfg.interpret,
                                  bricks=int(ell.n_tiles.sum()))
 
-        out, stats = self._stream(fwd, consume_one, width,
-                                  feat=FeatureSpec(n, width, 4, 0.0))
-        self.last_stream_stats = stats
-        self.forward_stats_log.append(stats)
-        return out
-
-    def gcn_layer(self, a: CSR, h: jax.Array, w: jax.Array,
-                  b: jax.Array) -> jax.Array:
-        """Differentiable fused layer Y = σ((A H) W + b), Fig. 1 chain.
-
-        Forward streams the fused Pallas kernel — the aggregation X never
-        round-trips through HBM. Backward therefore *recomputes* X with one
-        forward stream (activation recomputation), then:
-            dXW = dY ⊙ 1[Y>0];  dW = Xᵀ dXW;  db = Σ dXW;
-            dH  = Aᵀ (dXW Wᵀ)   — one transposed stream.
-        """
-        from repro.kernels import fused_gcn_layer
-
-        cfg = self.config
-        h = jnp.asarray(h)
-        w = jnp.asarray(w)
-        b = jnp.asarray(b)
-        fwd = self._prepare(a, h.shape, transpose=False)
-        dtypes = (h.dtype, w.dtype, b.dtype)
-
-        def run_fused(h_in, w_in, b_in):
-            h_dev = jax.device_put(h_in)
-            y, stats = self._stream(
-                fwd,
-                lambda ell_dev, ell, _: fused_gcn_layer(
-                    ell_dev, h_dev, w_in, b_in, interpret=cfg.interpret),
-                int(h_in.shape[1]))
-            self.last_stream_stats = stats
-            self.forward_stats_log.append(stats)
-            return y
-
-        @jax.custom_vjp
-        def layer(h_in, w_in, b_in):
-            return run_fused(h_in, w_in, b_in)
-
-        def layer_fwd(h_in, w_in, b_in):
-            y = run_fused(h_in, w_in, b_in)
-            return y, (h_in, w_in, y)
-
-        def layer_bwd(res, dy):
-            h_in, w_in, y = res
-            # Recompute X = A H with one forward stream (counted in the
-            # backward log: it is backward-phase I/O).
-            x, stats = self._stream_spmm(fwd, h_in)
-            self.backward_stats_log.append(stats)
-            dxw = dy * (y > 0).astype(dy.dtype)
-            dw = x.T.astype(jnp.float32) @ dxw.astype(jnp.float32)
-            db = jnp.sum(dxw, axis=0)
-            dx = dxw.astype(jnp.float32) @ w_in.T.astype(jnp.float32)
-            dh = self._backward_stream(a, dx)
-            return (dh.astype(dtypes[0]), dw.astype(dtypes[1]),
-                    db.astype(dtypes[2]))
-
-        layer.defvjp(layer_fwd, layer_bwd)
-        return layer(h, w, b)
+        return self._stream(fwd, consume_one, width)
 
 
 @dataclasses.dataclass

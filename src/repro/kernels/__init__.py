@@ -9,12 +9,11 @@ ops.py, and a pure-jnp oracle in ref.py.
 """
 from repro.kernels.ops import (
     bcsr_spmm,
-    fused_gcn_layer,
     gat_attention,
     decode_attention,
     flash_attention,
 )
 from repro.kernels import ref
 
-__all__ = ["bcsr_spmm", "fused_gcn_layer", "gat_attention",
+__all__ = ["bcsr_spmm", "gat_attention",
            "decode_attention", "flash_attention", "ref"]

@@ -261,112 +261,21 @@ def _split_spmm(blocks, col_tile, n_tiles, h, *, bm: int, bk: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "bn", "interpret", "out_dtype"),
+    static_argnames=("bm", "bk", "interpret", "out_dtype"),
 )
 def bcsr_spmm_pallas(
     blocks: jax.Array,     # (n_rb, ell_w, bm, bk)
     col_tile: jax.Array,   # (n_rb, ell_w) int32
     n_tiles: jax.Array,    # (n_rb,) int32
-    h: jax.Array,          # (K_pad, F_pad) — K_pad % bk == 0, F_pad % bn == 0
+    h: jax.Array,          # (K_pad, F_pad) — K_pad % bk == 0
     *,
     bm: int,
     bk: int,
-    bn: int,
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> jax.Array:
-    """X = A @ H for one segment; each step takes H's F_pad columns whole
-    (`bn` is the unit `h` was padded to)."""
-    del bn
+    """X = A @ H for one segment; each step takes H's F_pad columns whole."""
     n_rb, ell_w = blocks.shape[0], blocks.shape[1]
     return _split_spmm(blocks, col_tile, n_tiles, h, bm=bm, bk=bk,
                        interpret=interpret, out_dtype=out_dtype,
                        rows=smem_rows_per_call(n_rb, ell_w))
-
-
-def _fused_gcn_kernel(n_tiles_ref, col_tile_ref, a_ref, h_ref, w_ref, b_ref,
-                      o_ref, x_scratch):
-    """Fused σ((Σ_s A_s H_s) W + b) per row block (chain fusion, Fig. 1).
-
-    Grid (n_rb, ell_w): accumulate the aggregation X tile in VMEM scratch,
-    apply the combination matmul + bias + ReLU on the last reduction step —
-    X never round-trips to HBM.
-    """
-    rb = pl.program_id(0)
-    s = pl.program_id(1)
-    ell_w = pl.num_programs(1)
-
-    @pl.when(s == 0)
-    def _init():
-        x_scratch[...] = jnp.zeros_like(x_scratch)
-
-    @pl.when(s < n_tiles_ref[rb])
-    def _acc():
-        x_scratch[...] += jnp.dot(
-            a_ref[0, 0], h_ref[...], preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-
-    @pl.when(s == ell_w - 1)
-    def _combine():
-        x = x_scratch[...]
-        y = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
-        y = y + b_ref[...]
-        o_ref[...] = jnp.maximum(y, 0.0).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("bm", "bk", "interpret", "out_dtype"),
-)
-def fused_gcn_layer_pallas(
-    blocks: jax.Array,    # (n_rb, ell_w, bm, bk)
-    col_tile: jax.Array,  # (n_rb, ell_w)
-    n_tiles: jax.Array,   # (n_rb,)
-    h: jax.Array,         # (K_pad, F)
-    w: jax.Array,         # (F, F_out)
-    b: jax.Array,         # (F_out,)
-    *,
-    bm: int,
-    bk: int,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
-    n_rb, ell_w = blocks.shape[0], blocks.shape[1]
-    f = h.shape[1]
-    f_out = w.shape[1]
-    grid = (n_rb, ell_w)
-
-    out = pl.pallas_call(
-        _fused_gcn_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(
-                    (1, 1, bm, bk),
-                    lambda rb, s, n_tiles_ref, col_tile_ref: (rb, s, 0, 0),
-                ),
-                pl.BlockSpec(
-                    (bk, f),
-                    lambda rb, s, n_tiles_ref, col_tile_ref: (
-                        jnp.maximum(col_tile_ref[rb, s], 0), 0),
-                ),
-                pl.BlockSpec((f, f_out),
-                             lambda rb, s, *_: (0, 0)),
-                pl.BlockSpec((1, f_out),
-                             lambda rb, s, *_: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (bm, f_out),
-                lambda rb, s, n_tiles_ref, col_tile_ref: (rb, 0),
-            ),
-            scratch_shapes=[pltpu.VMEM((bm, f), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_rb * bm, f_out), out_dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(n_tiles, col_tile, blocks, h, w, b.reshape(1, -1))
-    return out

@@ -84,7 +84,6 @@ def bcsr_spmm(
             h_pad,
             bm=ell.bm,
             bk=ell.bk,
-            bn=bn,
             interpret=interpret,
             out_dtype=out_dtype,
         )
@@ -132,39 +131,6 @@ def gat_attention(
             bm=ell.bm,
             bk=ell.bk,
             interpret=interpret,
-        )
-        return out[: ell.n_rows]
-
-
-def fused_gcn_layer(
-    ell: BlockELL,
-    h: jax.Array,
-    w: jax.Array,
-    b: jax.Array,
-    *,
-    interpret: Optional[bool] = None,
-    out_dtype=jnp.float32,
-) -> jax.Array:
-    """σ((A @ H) @ W + b) fused per row block — Fig. 1 chain without
-    materializing X in HBM."""
-    if interpret is None:
-        interpret = _on_cpu()
-    with span("kernel", rows=ell.n_rows):
-        h_pad = _pad_to(jnp.asarray(h), 0, ell.bk)
-        need_k = _needed_rows(ell)
-        if h_pad.shape[0] < need_k:
-            h_pad = jnp.pad(h_pad, ((0, need_k - h_pad.shape[0]), (0, 0)))
-        out = _bcsr.fused_gcn_layer_pallas(
-            jnp.asarray(ell.blocks),
-            jnp.asarray(ell.col_tile),
-            jnp.asarray(ell.n_tiles),
-            h_pad,
-            jnp.asarray(w),
-            jnp.asarray(b),
-            bm=ell.bm,
-            bk=ell.bk,
-            interpret=interpret,
-            out_dtype=out_dtype,
         )
         return out[: ell.n_rows]
 

@@ -50,11 +50,6 @@ def gat_attn_ref(blocks, col_tile, n_tiles, z, s_src, s_dst, *, heads: int,
     return out / jnp.where(den > 0, den, 1.0)[:, :, None]
 
 
-def fused_gcn_layer_ref(blocks, col_tile, n_tiles, h, w, b, *, bm: int, bk: int):
-    x = bcsr_spmm_ref(blocks, col_tile, n_tiles, h, bm=bm, bk=bk)
-    return jnp.maximum(x @ w.astype(jnp.float32) + b.astype(jnp.float32), 0.0)
-
-
 def decode_attention_ref(q, k, v, lens):
     """(B, n_kv, group, d) GQA decode attention with per-seq valid lengths."""
     scale = 1.0 / (q.shape[-1] ** 0.5)

@@ -117,12 +117,6 @@ class EngineConfig:
     # Inter-chip link topology for the sharded cache's ICI charges (ring
     # vs all-to-all); all-to-all reproduces the former flat-link costing.
     ici_topology: ICITopology = ICI_ALL_TO_ALL
-    # Static plan analysis (repro.core.analysis) before every real stream:
-    # True forces it on, False off, None (default) defers to the module
-    # default — off in production, on under tests via tests/conftest.py.
-    # An error-severity finding raises PlanAnalysisError instead of
-    # streaming a semantically broken plan.
-    analyze_plans: Optional[bool] = None
     # Clock used for submit stamps, deadline expiry and EDF remaining-time
     # math. None (default) = `time.monotonic`. The continuous serving loop
     # (`repro.runtime.serving_loop`) injects a `VirtualClock` here so trace
@@ -388,8 +382,9 @@ class ServingEngine:
         # clock; a VirtualClock here puts the whole admission story on a
         # deterministic replay timeline.
         self.clock: Callable[[], float] = config.clock or time.monotonic
-        # Plan-rewrite pipeline every batch's stream plans route through
-        # (build → rewrite → interpret). A bare sequence of passes is
+        # Plan-rewrite pipeline every batch's stream plans route through:
+        # estimates price the rewritten plan, and real streams take its
+        # shard placements. A bare sequence of passes is
         # wrapped here; track_costs=False keeps per-stream estimates off
         # the serving hot path (scheduler runs still report deltas).
         pp = config.plan_passes
@@ -488,7 +483,6 @@ class ServingEngine:
             ),
             segment_cache=self.cache,
             plan_passes=self.plan_pipeline,
-            analyze=cfg.analyze_plans,
             partition=partition)
         self._engines[name] = eng
         if partition is not None and self.cache is not None:

@@ -30,15 +30,43 @@ def pytest_configure(config):
 @pytest.fixture(autouse=True, scope="session")
 def _analyze_plans_by_default():
     """Static plan analysis is on for the whole suite: every plan any test
-    interprets or streams is checked by `repro.core.analysis` first, and an
-    error-severity finding raises `PlanAnalysisError`. Production keeps the
-    default off; tests that deliberately interpret a broken plan opt out
-    with `analyze=False`."""
+    interprets (`CostInterpreter` / `ExecuteInterpreter.run`) is checked by
+    `repro.core.analysis` first, and an error-severity finding raises
+    `PlanAnalysisError`. Production keeps the default off; tests that
+    deliberately interpret a broken plan opt out with `analyze=False`. A
+    real `AiresSpGEMM` stream interprets no plan, here as on the chip: its
+    stream plans are checked where a test builds and analyzes them
+    (`AiresSpGEMM.stream_plan`, `PassPipeline(strict=True)`)."""
     from repro.core import analysis
 
     previous = analysis.set_default_analyze(True)
     yield
     analysis.set_default_analyze(previous)
+
+
+@pytest.fixture(scope="session")
+def assert_metrics_match():
+    """Check `ScheduleMetrics` fields against a golden record: integers,
+    booleans and byte counts exactly, floats to a relative 1e-12. A real
+    accounting change moves a modeled time by far more than that; the
+    order of a float sum moves it by an ULP."""
+
+    def check(metrics, want, fields, label):
+        for field in fields:
+            got, exp = getattr(metrics, field), want[field]
+            if isinstance(exp, dict):
+                assert set(got) == set(exp), f"{label}.{field}: keys differ"
+                pairs = [(f"{field}[{k}]", got[k], exp[k]) for k in exp]
+            else:
+                pairs = [(field, got, exp)]
+            for name, g, w in pairs:
+                if isinstance(w, float):
+                    ok = g == pytest.approx(w, rel=1e-12, abs=0.0)
+                else:
+                    ok = g == w
+                assert ok, f"{label}.{name}: {g!r} != golden {w!r}"
+
+    return check
 
 
 @pytest.fixture(scope="session")

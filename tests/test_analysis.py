@@ -438,25 +438,6 @@ def test_interpreter_analyze_default_on_under_tests():
     assert plan.estimate(SPEC).oom
 
 
-def test_engine_analyze_plans_flag(small_graph):
-    """EngineConfig.analyze_plans=True streams a real batch through the
-    execute interpreter's analysis gate."""
-    import jax.numpy as jnp
-    from repro.runtime import EngineConfig, InferenceRequest, ServingEngine
-
-    a = small_graph
-    rng = np.random.default_rng(0)
-    h = rng.standard_normal((a.n_rows, 8)).astype(np.float32)
-    engine = ServingEngine(EngineConfig(
-        device_budget_bytes=_budget(a, width=8), bm=8, bk=8,
-        max_batch_features=8, analyze_plans=True))
-    engine.register_graph("g", a)
-    engine.submit(InferenceRequest("g", jnp.asarray(h)))
-    report = engine.run_batch()
-    assert len(report.results) == 1
-    assert report.results[0].output is not None
-
-
 def test_spgemm_stream_plan_analyzes_clean(small_graph):
     a = small_graph
     budget = _budget(a, width=8)

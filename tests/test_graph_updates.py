@@ -284,7 +284,7 @@ def check_delta_update_end_to_end(seed):
     np.testing.assert_allclose(np.asarray(spg(a, jnp.asarray(h))),
                                dense @ h, atol=1e-4, rtol=1e-4)
     (old_key,) = list(spg._prepared)
-    old_keys = spg._segment_keys(spg._prepared[old_key])
+    old_keys = [e.key for e in spg._segment_entries(spg._prepared[old_key])]
 
     inserts, deletes = _random_delta(rng, a, dense)
     new, delta = apply_edge_updates(a, inserts=inserts, deletes=deletes)
@@ -294,7 +294,7 @@ def check_delta_update_end_to_end(seed):
 
     (new_key,) = list(spg._prepared)
     prep = spg._prepared[new_key]
-    new_keys = spg._segment_keys(prep)
+    new_keys = [e.key for e in spg._segment_entries(prep)]
     # Untouched keys survive verbatim (same namespace, id, fingerprint):
     # those are exactly the cache entries that keep hitting.
     surviving = set(old_keys) & set(new_keys)

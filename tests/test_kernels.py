@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import bcsr_spmm, decode_attention, fused_gcn_layer
+from repro.kernels import bcsr_spmm, decode_attention
 from repro.kernels.ref import decode_attention_ref
 from repro.sparse import csr_from_dense, tile_csr_to_block_ell
 
@@ -66,7 +66,7 @@ def test_bcsr_spmm_split_calls_bitexact(rows):
     args = (jnp.asarray(ell.blocks), jnp.asarray(ell.col_tile),
             jnp.asarray(ell.n_tiles), h)
     kw = dict(bm=8, bk=8, interpret=True)
-    whole = np.asarray(bcsr_spmm_pallas(*args, bn=128, **kw))
+    whole = np.asarray(bcsr_spmm_pallas(*args, **kw))
     split = np.asarray(_split_spmm(*args, out_dtype=jnp.float32, rows=rows,
                                    **kw))
     np.testing.assert_array_equal(split, whole)
@@ -195,21 +195,6 @@ def test_bcsr_spmm_span_counts(monkeypatch):
     assert seen[0] == ("kernel", dict(rows=40, grid_steps=steps))
     bcsr_spmm(dev, h, bn=8, bricks=7)
     assert seen[2] == ("kernel", dict(rows=40, grid_steps=steps, bricks=7))
-
-
-@pytest.mark.parametrize("n,f,fo", [(24, 16, 8), (40, 24, 16)])
-def test_fused_gcn_layer(n, f, fo):
-    dense = _rand_sparse(n, n, 0.2, np.float32, seed=n)
-    a = csr_from_dense(dense)
-    ell = tile_csr_to_block_ell(a, bm=8, bk=8)
-    rng = np.random.default_rng(5)
-    h = rng.standard_normal((n, f)).astype(np.float32)
-    w = rng.standard_normal((f, fo)).astype(np.float32)
-    b = rng.standard_normal((fo,)).astype(np.float32)
-    out = np.asarray(fused_gcn_layer(ell, jnp.asarray(h), jnp.asarray(w),
-                                     jnp.asarray(b)))
-    ref = np.maximum(dense @ h @ w + b, 0)
-    np.testing.assert_allclose(out, ref, atol=1e-3)
 
 
 @pytest.mark.parametrize("b,nq,nkv,s,d", [

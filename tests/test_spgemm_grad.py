@@ -83,28 +83,6 @@ def test_grad_streams_multiple_transposed_segments(make_sparse):
         "backward should stream the transposed plan"
 
 
-def test_fused_layer_param_grads(make_sparse):
-    """dH, dW, db through the fused σ((A H) W + b) streamed layer."""
-    a, dense, h = _case(make_sparse, 41, 41, 12, seed=11)
-    rng = np.random.default_rng(5)
-    w = jnp.asarray(rng.standard_normal((12, 6)).astype(np.float32))
-    b = jnp.asarray(rng.standard_normal((6,)).astype(np.float32))
-    eng = _engine(a, h.nbytes)
-
-    def loss(h_, w_, b_):
-        return jnp.sum(jnp.tanh(eng.gcn_layer(a, h_, w_, b_)))
-
-    def loss_ref(h_, w_, b_):
-        return jnp.sum(jnp.tanh(
-            jax.nn.relu(jnp.asarray(dense) @ h_ @ w_ + b_)))
-
-    args = (jnp.asarray(h), w, b)
-    grads = jax.grad(loss, argnums=(0, 1, 2))(*args)
-    refs = jax.grad(loss_ref, argnums=(0, 1, 2))(*args)
-    for g, r in zip(grads, refs):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-3)
-
-
 def test_gcn_model_grads_out_of_core(make_sparse):
     """Full GCN param grads via gcn_loss with the streamed engine vs the
     dense in-core path — covers W and bias grads of every layer."""

@@ -88,7 +88,7 @@ def test_bcsr_spmm_compiles_for_v5e(name, one_chip, no_persistent_cache):
         spec((n_rb, ell_w), jnp.int32),
         spec((n_rb,), jnp.int32),
         spec((k, F), jnp.float32),
-        bm=8, bk=8, bn=128)
+        bm=8, bk=8)
     assert "tpu_custom_call" in lowered.as_text()
     mem = lowered.compile().memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
